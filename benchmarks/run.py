@@ -71,7 +71,7 @@ def main() -> None:
 
     if args.compile_cache:
         from repro.runtime import compile_cache
-        compile_cache.enable(args.compile_cache)
+        args.compile_cache = compile_cache.enable(args.compile_cache)
 
     from benchmarks import (amrules_benchmarks, clustream_benchmarks,
                             ensemble_benchmarks, fleet_benchmarks,

@@ -10,6 +10,7 @@ honest measurements of this runtime, not projections.
 from __future__ import annotations
 
 import dataclasses
+import os
 import tempfile
 import time
 
@@ -284,7 +285,9 @@ def chunked_long_stream(fast=True):
             compile_cache_dir=ccdir).run(resume=True)
         cc1 = compile_cache.stats()
         # scope the cache to this arm: later arms time genuine compiles
-        jax.config.update("jax_compilation_cache_dir", None)
+        # (a cache placed from outside through the environment stays on)
+        jax.config.update("jax_compilation_cache_dir",
+                          os.environ.get(compile_cache.ENV_DIR))
     resume_cc = {k: cc1[k] - cc0[k] for k in cc1}
     resume_exact = (resumed.metric == res.metric
                     and resumed.curve == res.curve)

@@ -496,8 +496,9 @@ class ChunkedPrequentialEvaluation(Task):
         self.max_inflight_chunks = max(1, int(max_inflight_chunks))
         self.compile_cache_dir = compile_cache_dir
         if compile_cache_dir is not None:
+            # $JAX_COMPILATION_CACHE_DIR, when set, overrides the argument
             from repro.runtime import compile_cache
-            compile_cache.enable(compile_cache_dir)
+            self.compile_cache_dir = compile_cache.enable(compile_cache_dir)
         self.report: dict = {}
 
     def _save(self, chunk_index: int, carry, acc: MetricAccumulator):

@@ -85,14 +85,11 @@ _ACTIVE_MESH: contextvars.ContextVar = contextvars.ContextVar(
 @contextlib.contextmanager
 def mesh_context(mesh: Mesh):
     """Activate `mesh` for constrain()/active_mesh() AND as jax's resource
-    env -- through jax.sharding.use_mesh where it exists (newer jax), the
-    legacy Mesh context manager otherwise.  The contextvar is what model
-    code must consult (active_mesh()), since the jax-internal resource env
-    moved between versions."""
+    env (the Mesh context manager).  The contextvar is what model code
+    must consult (active_mesh()): jax's own resource env is internal."""
     token = _ACTIVE_MESH.set(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
     try:
-        with use_mesh(mesh) if use_mesh is not None else mesh:
+        with mesh:
             yield mesh
     finally:
         _ACTIVE_MESH.reset(token)
@@ -100,6 +97,40 @@ def mesh_context(mesh: Mesh):
 
 def active_mesh() -> Mesh | None:
     return _ACTIVE_MESH.get()
+
+
+def kernel_mesh() -> Mesh | None:
+    """The active mesh when it spans more than one device, else None.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel: under such a
+    mesh a kernel call must run inside a shard_map (``run_replicated``).
+    The kernel dispatchers read this at trace time and pass it on as a
+    static argument, so their compiled programs are keyed on it."""
+    mesh = _ACTIVE_MESH.get()
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def run_per_shard(fn, mesh: Mesh, in_specs, out_specs, *args):
+    """``fn`` on each device's shard of ``args`` (a shard_map over
+    ``mesh``).  Kernel dispatchers traced inside see no active mesh: a
+    shard is one device's problem, so they call their kernel directly."""
+    token = _ACTIVE_MESH.set(None)
+    try:
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
+    finally:
+        _ACTIVE_MESH.reset(token)
+
+
+def run_replicated(fn, mesh: Mesh | None, *args):
+    """``fn(*args)``; under a multi-device ``mesh`` inside a shard_map
+    whose inputs and outputs are replicated.  Every device then runs the
+    whole call on gathered inputs -- bit-identical to one device -- and
+    GSPMD reshards the result to whatever layout the caller constrains."""
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 _SUPPRESS_SPMD_GATHER: contextvars.ContextVar = contextvars.ContextVar(

@@ -32,8 +32,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block, causal, window,
 
     def body(ki, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.ds(ki * kv_block, kv_block), slice(None)))
-        v = pl.load(v_ref, (pl.ds(ki * kv_block, kv_block), slice(None)))
+        k = k_ref[pl.ds(ki * kv_block, kv_block), :]
+        v = v_ref[pl.ds(ki * kv_block, kv_block), :]
         s = jax.lax.dot_general(q, k.astype(f32), (((1,), (1,)), ((), ())),
                                 preferred_element_type=f32)  # [qb, kvb]
         kpos = ki * kv_block + jax.lax.broadcasted_iota(

@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 @partial(jax.jit, static_argnames=("causal", "window", "use_pallas",
                                    "interpret", "q_block", "kv_block"))
 def flash_attention(q, k, v, *, causal=True, window=0, use_pallas=True,
-                    interpret=True, q_block=512, kv_block=512):
+                    interpret=False, q_block=512, kv_block=512):
     """q: [B,S,H,hd]; k/v: [B,T,K,hd] with H = K*G.
 
     The wrapper expands GQA kv heads (on TPU the kernel would index the

@@ -5,8 +5,8 @@ Three implementations of the same contraction
 (instances with seg == R are discarded):
 
   pallas   -- one-hot MXU matmuls, statistics tile resident in VMEM
-              (kernel.py).  Default on TPU; `interpret` fallback runs the
-              kernel body on CPU for validation.
+              (kernel.py).  Default on TPU; off TPU it runs only in
+              interpret mode, and only when the caller asks for it.
   segment  -- per-moment element scatter: each (instance, attribute) pair
               adds mom[i, c] at (seg_i, j, xbin_ij).  Never materializes
               the [B, m, bins] bin one-hot, let alone the dense
@@ -28,6 +28,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import kernel_mesh, run_replicated
 from repro.kernels.rule_stats.kernel import rule_stats_pallas
 from repro.kernels.rule_stats.ref import rule_stats_ref
 
@@ -67,17 +68,27 @@ def rule_stats_update_segment(stats, seg, xbin, mom):
     return stats
 
 
-@partial(jax.jit, static_argnames=("impl", "attr_tile", "interpret"))
 def rule_stats_update(stats, seg, xbin, mom, *, impl: str = "auto",
-                      attr_tile: int = 0, interpret: bool | None = None):
+                      attr_tile: int = 0, interpret: bool = False):
     """Accumulate weighted-moment statistics for a micro-batch.
 
     stats: [R, m, bins, C]; seg: [B] i32 in [0, R] (R = discard);
     xbin: [B, m] i32; mom: [B, C] f32.  impl="auto" picks Pallas on TPU and
     the segment scatter elsewhere; `attr_tile` overrides the Pallas
-    kernel's heuristic attribute tile; `interpret=None` auto-enables
-    interpret mode off-TPU.
+    kernel's heuristic attribute tile; `interpret=True` runs the Pallas
+    kernel body in interpret mode (for validation off TPU).  Under a
+    multi-device mesh the kernel runs replicated inside a shard_map
+    (``run_replicated``).
     """
+    return _rule_stats_update(stats, seg, xbin, mom, impl=impl,
+                              attr_tile=attr_tile, interpret=interpret,
+                              mesh=kernel_mesh())
+
+
+@partial(jax.jit,
+         static_argnames=("impl", "attr_tile", "interpret", "mesh"))
+def _rule_stats_update(stats, seg, xbin, mom, *, impl, attr_tile,
+                       interpret, mesh):
     if impl == "auto":
         impl = default_impl()
     if impl == "onehot":
@@ -86,7 +97,6 @@ def rule_stats_update(stats, seg, xbin, mom, *, impl: str = "auto",
         return rule_stats_update_segment(stats, seg, xbin, mom)
     if impl != "pallas":
         raise ValueError(f"unknown stats impl {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return rule_stats_pallas(stats, seg, xbin, mom,
-                             attr_tile=attr_tile, interpret=interpret)
+    return run_replicated(
+        partial(rule_stats_pallas, attr_tile=attr_tile, interpret=interpret),
+        mesh, stats, seg, xbin, mom)

@@ -35,8 +35,7 @@ def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hT_ref):
         dBx = (dt_t * x_t)[:, None] * b_t[None, :]
         h = dA * h + dBx
         y = (h * c_t[None, :]).sum(-1)                # [dT]
-        pl.store(y_ref, (pl.ds(t, 1), slice(None)),
-                 y[None].astype(y_ref.dtype))
+        y_ref[pl.ds(t, 1), :] = y[None].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, c_len, step, h0_ref[...].astype(f32))

@@ -6,8 +6,8 @@ for every ensemble member at once):
 
   pallas  -- shared-prefix one-hot gather program on the MXU: the member's
              node tables live in VMEM and every depth step is one
-             [B, N] x [N, 4] matmul (kernel.py).  Default on TPU;
-             `interpret` fallback runs the kernel body off-TPU for parity.
+             [4, N] x [N, B] matmul (kernel.py).  Default on TPU; off
+             TPU it runs only in interpret mode, when the caller asks.
   gather  -- flattened-table formulation: all M node tables concatenate to
              one [M*N] array and every depth step is a handful of flat 1-D
              takes over [M*B] indices -- no batched (vmap-of-gather)
@@ -31,6 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import kernel_mesh, run_replicated
 from repro.kernels.tree_route.kernel import tree_route_pallas
 from repro.kernels.tree_route.ref import tree_route_ref
 
@@ -80,17 +81,27 @@ def tree_route_gather(split_attr, split_bin, children, xbin, max_depth: int):
     return node - base
 
 
-@partial(jax.jit, static_argnames=("max_depth", "impl", "interpret"))
 def tree_route(split_attr, split_bin, children, xbin, *, max_depth: int,
-               impl: str = "auto", interpret: bool | None = None):
+               impl: str = "auto", interpret: bool = False):
     """Route a shared [B, m] micro-batch through M trees -> leaf ids.
 
     split_attr/split_bin: [M, N] (or [N] for a single tree);
     children: [M, N, 2] (or [N, 2]); xbin: [B, m] i32.  Returns [M, B]
     ([B] when the tables were rank-1).  impl="auto" picks Pallas on TPU
     and the flat-gather formulation elsewhere; "fori" is the legacy
-    oracle; `interpret=None` auto-enables Pallas interpret mode off-TPU.
+    oracle; `interpret=True` runs the Pallas kernel in interpret mode.
+    Under a multi-device mesh the kernel runs replicated inside a
+    shard_map (``run_replicated``).
     """
+    return _tree_route(split_attr, split_bin, children, xbin,
+                       max_depth=max_depth, impl=impl, interpret=interpret,
+                       mesh=kernel_mesh())
+
+
+@partial(jax.jit,
+         static_argnames=("max_depth", "impl", "interpret", "mesh"))
+def _tree_route(split_attr, split_bin, children, xbin, *, max_depth, impl,
+                interpret, mesh):
     single = split_attr.ndim == 1
     if single:
         split_attr = split_attr[None]
@@ -104,10 +115,10 @@ def tree_route(split_attr, split_bin, children, xbin, *, max_depth: int,
         out = tree_route_gather(split_attr, split_bin, children, xbin,
                                 max_depth)
     elif impl == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        out = tree_route_pallas(split_attr, split_bin, children, xbin,
-                                max_depth, interpret=interpret)
+        out = run_replicated(
+            partial(tree_route_pallas, max_depth=max_depth,
+                    interpret=interpret),
+            mesh, split_attr, split_bin, children, xbin)
     else:
         raise ValueError(f"unknown route impl {impl!r}")
     return out[0] if single else out

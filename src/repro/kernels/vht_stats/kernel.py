@@ -2,83 +2,29 @@
 
 TPU adaptation of the paper's LS update (Alg. 2).  A scatter-add over
 (leaf, attr, bin, class) is hostile to the TPU (serialized scatter); we
-reformulate per attribute tile:
+reformulate it as the weighted-moments contraction of
+repro.kernels.rule_stats with the class one-hot as the moment matrix:
 
-    delta[n, j, b, c] = sum_i leaf1h[i, n] * (bin1h[i, j, b] * cls1h[i, c])
-                      = (leaf1h^T  @  V)      with V = bin1h (x) cls1h
+    delta[n, j, b, c] = sum_i leaf1h[i, n] * bin1h[i, j, b] * (y1h[i, c] w_i)
 
-one [N, B] x [B, ja*bins*C] matmul per attribute tile -- MXU work, fully
-vectorized, with the statistics tile resident in VMEM and accumulated
-in-place (input_output_aliasing).  Grid = attribute tiles; one-hots are
-built in-kernel with broadcasted_iota comparisons (no HBM one-hot).
+one [nt, B] x [B, ja*bins*C] matmul per statistics block -- MXU work over
+the lane-dense 2-D view of the statistics, accumulated in place, with the
+one-hots built in-kernel from iota comparisons (no HBM one-hot).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.rule_stats.kernel import rule_stats_pallas
 
 f32 = jnp.float32
-
-
-def _kernel(leaf_ref, y_ref, w_ref, xbin_ref, stats_in_ref, stats_ref, *,
-            n_nodes, n_bins, n_classes):
-    B = leaf_ref.shape[0]
-    ja = xbin_ref.shape[1]
-
-    leaf = leaf_ref[...]                                   # [B]
-    nodes = jax.lax.broadcasted_iota(jnp.int32, (B, n_nodes), 1)
-    leaf1h = (leaf[:, None] == nodes).astype(f32)          # [B, N]
-
-    y1h = (y_ref[...][:, None]
-           == jax.lax.broadcasted_iota(jnp.int32, (B, n_classes), 1))
-    ycw = y1h.astype(f32) * w_ref[...][:, None]            # [B, C]
-
-    xb = xbin_ref[...]                                     # [B, ja]
-    bins = jax.lax.broadcasted_iota(jnp.int32, (B, ja, n_bins), 2)
-    bin1h = (xb[:, :, None] == bins).astype(f32)           # [B, ja, bins]
-
-    # V[i, j, b, c] = bin1h * ycw  -> flatten to [B, ja*bins*C]
-    v = bin1h[:, :, :, None] * ycw[:, None, None, :]
-    v2 = v.reshape(B, ja * n_bins * n_classes)
-
-    delta = jax.lax.dot_general(
-        leaf1h, v2, (((0,), (0,)), ((), ())),
-        preferred_element_type=f32)                        # [N, ja*bins*C]
-    stats_ref[...] = (stats_in_ref[...]
-                      + delta.reshape(n_nodes, ja, n_bins, n_classes))
 
 
 def stats_update_pallas(stats, leaf, xbin, y, w, *, attr_tile: int = 0,
                         interpret: bool = False):
     """stats: [N, m, bins, C]; returns updated stats (aliased in-place)."""
-    N, m, nb, C = stats.shape
-    B = leaf.shape[0]
-    ja = attr_tile or min(m, max(128 // max(nb * C // 8, 1), 8))
-    ja = min(ja, m)
-    # pad attribute axis to a tile multiple
-    mp = -(-m // ja) * ja
-    if mp != m:
-        xbin = jnp.pad(xbin, ((0, 0), (0, mp - m)))
-        stats = jnp.pad(stats, ((0, 0), (0, mp - m), (0, 0), (0, 0)))
-
-    kern = functools.partial(_kernel, n_nodes=N, n_bins=nb, n_classes=C)
-    out = pl.pallas_call(
-        kern,
-        grid=(mp // ja,),
-        in_specs=[
-            pl.BlockSpec((B,), lambda j: (0,)),            # leaf
-            pl.BlockSpec((B,), lambda j: (0,)),            # y
-            pl.BlockSpec((B,), lambda j: (0,)),            # w
-            pl.BlockSpec((B, ja), lambda j: (0, j)),       # xbin tile
-            pl.BlockSpec((N, ja, nb, C), lambda j: (0, j, 0, 0)),  # stats in
-        ],
-        out_specs=pl.BlockSpec((N, ja, nb, C), lambda j: (0, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(stats.shape, stats.dtype),
-        input_output_aliases={4: 0},                       # stats aliased
-        interpret=interpret,
-    )(leaf, y, w.astype(f32), xbin, stats)
-    return out[:, :m] if mp != m else out
+    mom = jax.nn.one_hot(y, stats.shape[3], dtype=f32) * w.astype(f32)[:, None]
+    return rule_stats_pallas(stats, leaf, xbin, mom, attr_tile=attr_tile,
+                             interpret=interpret)

@@ -4,8 +4,8 @@ Three implementations of the same contraction
 ``stats[n, j, b, c] += sum_i 1[leaf_i = n] 1[x_ij = b] 1[y_i = c] w_i``:
 
   pallas   -- one-hot MXU matmuls, statistics tile resident in VMEM
-              (kernel.py).  Default on TPU; `interpret` fallback runs the
-              kernel body on CPU for validation.
+              (kernel.py).  Default on TPU; off TPU it runs only in
+              interpret mode, and only when the caller asks for it.
   segment  -- class-segmented segment-sum: one [B, m, bins] leaf-segment
               scatter per class slice.  Never materializes the dense
               [B, m, bins, C] one-hot product (peak intermediate memory
@@ -21,6 +21,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import kernel_mesh, run_replicated
 from repro.kernels.vht_stats.kernel import stats_update_pallas
 from repro.kernels.vht_stats.ref import stats_update_ref
 
@@ -45,15 +46,25 @@ def stats_update_segment(stats, leaf, xbin, y, w):
     return stats
 
 
-@partial(jax.jit, static_argnames=("impl", "attr_tile", "interpret"))
 def stats_update(stats, leaf, xbin, y, w, *, impl: str = "auto",
-                 attr_tile: int = 0, interpret: bool | None = None):
+                 attr_tile: int = 0, interpret: bool = False):
     """Accumulate VHT sufficient statistics for a micro-batch.
 
     impl="auto" picks Pallas on TPU and the segment-sum formulation
     elsewhere; `attr_tile` overrides the Pallas kernel's heuristic
-    attribute tile; `interpret=None` auto-enables interpret mode off-TPU.
+    attribute tile; `interpret=True` runs the Pallas kernel body in
+    interpret mode (for validation off TPU).  Under a multi-device mesh
+    the kernel runs replicated inside a shard_map (``run_replicated``).
     """
+    return _stats_update(stats, leaf, xbin, y, w, impl=impl,
+                         attr_tile=attr_tile, interpret=interpret,
+                         mesh=kernel_mesh())
+
+
+@partial(jax.jit,
+         static_argnames=("impl", "attr_tile", "interpret", "mesh"))
+def _stats_update(stats, leaf, xbin, y, w, *, impl, attr_tile, interpret,
+                  mesh):
     if impl == "auto":
         impl = default_impl()
     if impl == "onehot":
@@ -62,7 +73,6 @@ def stats_update(stats, leaf, xbin, y, w, *, impl: str = "auto",
         return stats_update_segment(stats, leaf, xbin, y, w)
     if impl != "pallas":
         raise ValueError(f"unknown stats impl {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return stats_update_pallas(stats, leaf, xbin, y, w,
-                               attr_tile=attr_tile, interpret=interpret)
+    return run_replicated(
+        partial(stats_update_pallas, attr_tile=attr_tile,
+                interpret=interpret), mesh, stats, leaf, xbin, y, w)

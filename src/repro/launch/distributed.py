@@ -5,9 +5,11 @@ workers.  This module is the process-group wiring that makes the fused
 chunk program actually span processes:
 
   * :func:`initialize` -- bootstrap ``jax.distributed`` for one worker
-    (coordinator address, process index/count), forcing CPU host devices
-    and the gloo cross-process collective backend BEFORE the jax backend
-    initializes (both are read exactly once).
+    (coordinator address, process index/count), forcing the host device
+    count when asked and selecting the gloo cross-process collective
+    backend for CPU devices BEFORE the jax backend initializes (both are
+    read exactly once).  The platform is the caller's: the test launcher
+    sets ``JAX_PLATFORMS=cpu`` in each worker's environment.
   * :func:`init_from_env` -- the same, driven by ``REPRO_DIST_*``
     environment variables, so a worker script needs no argument parsing.
   * :func:`make_global_stream_mesh` -- the global device mesh over EVERY
@@ -63,16 +65,12 @@ def initialize(coordinator_address: str, num_processes: int,
     process_count, global_device_count)``.
     """
     if local_devices is not None:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         if not force_host_devices(int(local_devices)):
             raise RuntimeError(
                 "initialize() must run before jax creates its backends; "
                 "spawn a fresh process (see launch_workers)")
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # non-CPU platforms / jax versions without the knob
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes),
@@ -123,7 +121,8 @@ def make_global_stream_mesh(model: int | None = None,
     if model * data != n:
         raise ValueError(
             f"mesh {model}x{data} does not cover the {n} global devices")
-    return jax.make_mesh((model, data), ("model", "data"))
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((model, data), ("model", "data"))
 
 
 def payload_sharding(mesh, *, batch_axis: str = "data", batch_dim: int = 1):

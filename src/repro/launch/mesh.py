@@ -9,19 +9,33 @@ benchmarks must see the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes by default, under which
+    GSPMD-style code (sharding constraints on the carry, propagation
+    through gathers) must annotate every ``out_sharding`` by hand.  The
+    engines rely on propagation, so every mesh in the repo is built
+    here."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi-pod = 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Elastic mesh factory: any (pods, data, model) factorization of the
     currently visible devices (used by restart-after-failure paths)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return auto_mesh(shape, axes)
 
 
 def make_mesh_from_proposal(shape, axes):
@@ -50,7 +64,7 @@ def make_local_mesh(model_parallel: int = 1):
     """Single-host mesh over whatever devices exist (tests/examples)."""
     n = jax.device_count()
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return auto_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def make_stream_mesh(axis: str = "model"):
@@ -65,7 +79,7 @@ def make_stream_mesh(axis: str = "model"):
         raise ValueError(f"unknown stream axis {axis!r}")
     n = jax.device_count()
     shape = (n, 1) if axis == "model" else (1, n)
-    return jax.make_mesh(shape, ("model", "data"))
+    return auto_mesh(shape, ("model", "data"))
 
 
 FORCE_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
@@ -95,16 +109,9 @@ def force_host_devices(n: int, env=None) -> bool:
         # a smaller pre-existing count would silently mis-label the run
         env["XLA_FLAGS"] = flags.replace(have.group(0), flag)
     if "jax" in sys.modules:
-        try:  # already-initialized backends ignore new XLA_FLAGS
-            from jax._src import xla_bridge
-            if not xla_bridge.backends_are_initialized():
-                return True       # flag landed before first init
-        except Exception:
-            pass  # private probe moved between jax versions: fall through
-        try:
-            # initializes the backends now (with the flag we just set)
-            # when nothing was initialized yet, else reports the real count
-            return jax.device_count() >= n
-        except Exception:
-            return True           # cannot probe; the flag IS in the env
+        # already-initialized backends ignore new XLA_FLAGS
+        from jax._src import xla_bridge
+        if not xla_bridge.backends_are_initialized():
+            return True           # flag landed before first init
+        return jax.device_count() >= n
     return True

@@ -60,6 +60,22 @@ BIG = 1e30
 CNT, SUM, SQ = 0, 1, 2
 
 
+def batch_sum(x):
+    """Sum of a float batch vector in a fixed pairwise order: halves are
+    added elementwise until one value is left.  A reduce's association
+    is the backend's choice and changes with the array's shape -- a
+    fleet reduces its batches as one [F, B] array on one device but as
+    [1, B] slices when the tenant axis is sharded -- which moved the
+    float error sums by an ulp; elementwise adds are never
+    reassociated, so every layout and sharding gives the same bits."""
+    while x.shape[-1] > 1:
+        if x.shape[-1] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[..., :1])], -1)
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
 @dataclasses.dataclass(frozen=True)
 class RulesConfig:
     n_attrs: int
@@ -273,7 +289,7 @@ class AMRules:
         # ---- default rule head with uncovered instances ------------------
         w = (~covered).astype(f32)
         state["d_n"] = state["d_n"] + w.sum()
-        state["d_sum"] = state["d_sum"] + (w * y).sum()
+        state["d_sum"] = state["d_sum"] + batch_sum(w * y)
         state["d_since"] = state["d_since"] + w.sum()
 
         # ---- Page-Hinkley drift eviction (packed detector bank) ----------
@@ -309,8 +325,8 @@ class AMRules:
         state["n_rules"] = jnp.sum(state["active"].astype(i32))
 
         metrics = {
-            "abs_err": abs_err.sum(),
-            "sq_err": jnp.square(err).sum(),
+            "abs_err": batch_sum(abs_err),
+            "sq_err": batch_sum(jnp.square(err)),
             "seen": jnp.asarray(y.shape[0], f32),
             "n_rules": jnp.sum(state["active"].astype(f32)),
         }
@@ -573,7 +589,7 @@ class HAMR:
         # ---- centralized default-rule learner (head) ---------------------
         w = (~flat_cov).astype(f32)
         merged["d_n"] = state["d_n"] + w.sum()
-        merged["d_sum"] = state["d_sum"] + (w * flat_y).sum()
+        merged["d_sum"] = state["d_sum"] + batch_sum(w * flat_y)
         merged["d_since"] = state["d_since"] + w.sum()
 
         # ---- shared expansion/drift machinery (delayed broadcast) --------
@@ -582,7 +598,8 @@ class HAMR:
         merged = self._inner._try_default_expand(merged)
         merged["n_rules"] = jnp.sum(merged["active"].astype(i32))
 
-        metrics = {"abs_err": abse.sum(), "sq_err": sqe.sum(),
+        metrics = {"abs_err": batch_sum(abse.reshape(-1)),
+                   "sq_err": batch_sum(sqe.reshape(-1)),
                    "seen": jnp.asarray(Bs, f32),
                    "n_rules": jnp.sum(merged["active"].astype(f32))}
         return merged, metrics

@@ -31,22 +31,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.sharding import active_mesh
+
 f32 = jnp.float32
 i32 = jnp.int32
 
 
-def _active_mesh():
-    """The device mesh installed by ShardMapEngine's mesh_context (None
-    when tracing outside any mesh, i.e. the plain jit/scan path).  Falls
-    back to jax's legacy resource env so a bare ``with mesh:`` around a
-    hand-rolled trace is honoured too."""
-    from repro.distributed.sharding import active_mesh
-    m = active_mesh()
-    if m is not None:
-        return m
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,7 +188,7 @@ def macro_cluster(state, cc: CluStreamConfig, key=None):
     impl = _impl(cc)
     cent = _centroids(state)
     w = state["n"]
-    mesh = _active_mesh()
+    mesh = active_mesh()
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
         rep = NamedSharding(mesh, PartitionSpec())

@@ -303,8 +303,6 @@ class OzaEnsemble:
         stats, and apply_splits consumes scattered values only where
         ``should`` is True -- so filler-row selection order cannot leak
         into the result."""
-        from jax.experimental.shard_map import shard_map
-
         tc, tci, ec = self.tc, self._tc_inner, self.ec
         M, N, C = ec.n_members, tc.max_nodes, tc.n_classes
         K = min(tc.check_tile, M * N)
@@ -362,8 +360,8 @@ class OzaEnsemble:
             return jax.lax.cond(landed > 0, apply_members, lambda t: t, out)
 
         specs = jax.tree.map(lambda _: P("data"), ts)
-        return shard_map(shard_fn, mesh=mesh, in_specs=(specs,),
-                         out_specs=specs, check_rep=False)(ts)
+        return jax.shard_map(shard_fn, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False)(ts)
 
     def run(self, state, x_stream, y_stream):
         def body(st, xy):

@@ -288,16 +288,37 @@ class LocalStatisticProcessor(Processor):
         from jax.sharding import PartitionSpec as P
         return {"stats": P(None, "model", None, None)}
 
+    def _update(self, stats, ev):
+        """Alg. 2 on the attribute events.  Under a mesh whose 'model'
+        axis splits the attributes, each device updates its own attribute
+        slice (a shard_map over the key grouping): the instances are
+        replicated, the statistics never move, and the counters are
+        exact, so the result is bit-identical to one device."""
+        from repro.distributed.sharding import kernel_mesh, run_per_shard
+        from repro.kernels.vht_stats.ops import stats_update
+        tc = self.tc
+
+        def update(stats, leaf, x, y):
+            w = jnp.ones(y.shape[0], f32)
+            return stats_update(stats, leaf, x, y, w, impl=tc.stats_impl,
+                                attr_tile=tc.attr_tile)
+
+        args = (stats, ev["leaf"], ev["x"], ev["y"])
+        mesh = kernel_mesh()
+        n = mesh.shape.get("model", 1) if mesh is not None else 1
+        if n == 1 or tc.n_attrs % n:
+            return update(*args)
+        from jax.sharding import PartitionSpec as P
+        spec = P(None, "model", None, None)
+        return run_per_shard(update, mesh,
+                             (spec, P(), P(None, "model"), P()), spec, *args)
+
     def process(self, state, inputs):
         tc = self.tc
         out = {}
         attr_ev = inputs.get("attribute")
         if attr_ev is not None:
-            from repro.kernels.vht_stats.ops import stats_update
-            w = jnp.ones(attr_ev["y"].shape[0], f32)
-            state = {"stats": stats_update(
-                state["stats"], attr_ev["leaf"], attr_ev["x"], attr_ev["y"],
-                w, impl=tc.stats_impl, attr_tile=tc.attr_tile)}
+            state = {"stats": self._update(state["stats"], attr_ev)}
         comp = inputs.get("compute")
         if comp is not None:
             N, C = tc.max_nodes, tc.n_classes

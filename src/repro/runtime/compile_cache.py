@@ -12,6 +12,7 @@ nobody verified.
 
 from __future__ import annotations
 
+import os
 import threading
 
 _lock = threading.Lock()
@@ -21,6 +22,7 @@ _enabled_dir: str | None = None
 
 _REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 
 def _listener(event: str, **kw) -> None:
@@ -32,22 +34,21 @@ def _listener(event: str, **kw) -> None:
 
 
 def enable(cache_dir) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+    """Point jax's persistent compilation cache at ``cache_dir`` -- or at
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set: a cache placed from
+    outside always wins over the caller's default.
 
     Zeroes the min-compile-time / min-entry-size gates (the chunk
     programs are small but recompiled constantly across restarts) and
     registers the hit/miss listener once.  Safe to call repeatedly; the
-    last directory wins (jax reads the config per compile)."""
+    last directory wins (jax reads the config per compile).  Returns the
+    directory in use."""
     global _listening, _enabled_dir
     import jax
-    cache_dir = str(cache_dir)
+    cache_dir = os.environ.get(ENV_DIR) or str(cache_dir)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:
-            pass  # knob renamed/absent on this jax version
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     with _lock:
         if not _listening:
             jax.monitoring.register_event_listener(_listener)

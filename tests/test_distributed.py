@@ -25,7 +25,8 @@ def mesh():
     import math
     n = jax.device_count()
     data = math.gcd(4, n)
-    return jax.make_mesh((data, n // data), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh((data, n // data), ("data", "model"))
 
 
 # ---------------------------- param_spec rules -------------------------------

@@ -204,7 +204,8 @@ def test_rule_stats_matches_onehot_ref(impl, R):
     seg = jax.random.randint(ks[1], (B,), 0, R + 1)     # R = discard
     xbin = jax.random.randint(ks[2], (B, m), 0, nb)
     mom = rule_moments(jax.random.uniform(ks[3], (B,)) * 2 - 1)
-    out = rule_stats_update(stats, seg, xbin, mom, impl=impl)
+    out = rule_stats_update(stats, seg, xbin, mom, impl=impl,
+                            interpret=True)
     ref = rule_stats_ref(stats, seg, xbin, mom)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -367,7 +368,8 @@ def test_tree_route_matches_fori_oracle(impl, M):
     legacy per-member fori_loop, including the M == 1 fast path."""
     sa, sb, ch, xb = _random_tables(jax.random.PRNGKey(3), M, 31, 12, 8)
     ref = tree_route(sa, sb, ch, xb, max_depth=10, impl="fori")
-    out = tree_route(sa, sb, ch, xb, max_depth=10, impl=impl)
+    out = tree_route(sa, sb, ch, xb, max_depth=10, impl=impl,
+                     interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -556,10 +558,11 @@ def test_shard_map_engine_shards_bare_learner_state(reg_stream):
     tests/test_multidevice.py forces exactly that."""
     from jax.sharding import PartitionSpec as P
     from repro.core.engines import ShardMapEngine
+    from repro.launch.mesh import auto_mesh
     xs, ys = reg_stream
     n = jax.device_count()
     model = n if RC.max_rules % n == 0 else 1
-    mesh = jax.make_mesh((model, n // model), ("model", "data"))
+    mesh = auto_mesh((model, n // model), ("model", "data"))
     vamr = VAMR(RC)
     eng = ShardMapEngine(mesh)
     carry = eng.init(vamr, jax.random.PRNGKey(0))

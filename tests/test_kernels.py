@@ -32,7 +32,8 @@ def test_vht_stats_matches_ref(N, m, nb, C, B):
     xbin = jax.random.randint(k3, (B, m), 0, nb)
     y = jax.random.randint(k4, (B,), 0, C)
     w = jnp.where(jnp.arange(B) % 3 == 0, 0.0, 1.0)  # mixed weights
-    out = stats_update(stats, leaf, xbin, y, w, impl="pallas")
+    out = stats_update(stats, leaf, xbin, y, w, impl="pallas",
+                       interpret=True)
     ref = stats_update_ref(stats, leaf, xbin, y, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -48,7 +49,7 @@ def test_vht_stats_attr_tile_override():
     ref = stats_update_ref(stats, leaf, xbin, y, w)
     for tile in (4, 5, 12):      # including a non-divisor (padding path)
         out = stats_update(stats, leaf, xbin, y, w, impl="pallas",
-                           attr_tile=tile)
+                           attr_tile=tile, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
@@ -58,7 +59,8 @@ def test_vht_stats_weight_zero_is_noop(impl):
     stats = jnp.ones((8, 4, 4, 2))
     out = stats_update(stats, jnp.zeros(16, jnp.int32),
                        jnp.zeros((16, 4), jnp.int32),
-                       jnp.zeros(16, jnp.int32), jnp.zeros(16), impl=impl)
+                       jnp.zeros(16, jnp.int32), jnp.zeros(16), impl=impl,
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(stats))
 
 
@@ -72,15 +74,43 @@ def test_vht_stats_weight_zero_is_noop(impl):
 def test_split_gain_matches_ref(N, m, nb, C):
     key = jax.random.PRNGKey(N * m)
     stats = jax.random.uniform(key, (N, m, nb, C)) * 10
-    out = split_gain(stats, impl="pallas")
+    out = split_gain(stats, impl="pallas", interpret=True)
     ref = split_gain_ref(stats)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
 
 
 def test_split_gain_empty_stats_invalid():
-    g = split_gain(jnp.zeros((4, 3, 4, 2)), impl="pallas")
+    g = split_gain(jnp.zeros((4, 3, 4, 2)), impl="pallas", interpret=True)
     assert float(g.max()) <= -1e29  # no valid threshold on empty stats
+
+
+def test_split_gain_partial_blocks_match_ref():
+    """Node and column tiles that do not divide the statistics: the
+    out-of-range part of the last blocks is masked and never written."""
+    from repro.kernels.split_gain.kernel import split_gain_pallas
+    stats = jnp.floor(jax.random.uniform(jax.random.PRNGKey(5),
+                                         (33, 17, 4, 2)) * 10)
+    out = split_gain_pallas(stats, node_tile=8, col_tile=32, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(split_gain_ref(stats)),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_vht_stats_partial_node_block_is_exact():
+    """More nodes than one node tile, not a tile multiple (the 4095-node
+    deployment's shape class): integer counts stay exact."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    N, m, B = 600, 5, 32
+    stats = jnp.floor(jax.random.uniform(ks[0], (N, m, 8, 2)) * 5)
+    leaf = jax.random.randint(ks[1], (B,), 0, N).at[0].set(N - 1)
+    xbin = jax.random.randint(ks[2], (B, m), 0, 8)
+    y = jax.random.randint(ks[3], (B,), 0, 2)
+    w = jnp.ones((B,))
+    out = stats_update(stats, leaf, xbin, y, w, impl="pallas",
+                       interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(stats_update_ref(stats, leaf, xbin, y, w)))
 
 
 # --------------------------- flash_attention --------------------------------
@@ -96,7 +126,7 @@ def test_flash_attention_matches_ref(B, S, H, K, hd, dtype):
     q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, K, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, K, hd), dtype)
-    out = flash_attention(q, k, v, q_block=64, kv_block=64)
+    out = flash_attention(q, k, v, interpret=True, q_block=64, kv_block=64)
     ref = flash_attention(q, k, v, use_pallas=False)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -109,7 +139,7 @@ def test_flash_attention_window(window):
     q = jax.random.normal(ks[0], (2, 256, 4, 64))
     k = jax.random.normal(ks[1], (2, 256, 2, 64))
     v = jax.random.normal(ks[2], (2, 256, 2, 64))
-    out = flash_attention(q, k, v, q_block=64, kv_block=64, window=window)
+    out = flash_attention(q, k, v, interpret=True, q_block=64, kv_block=64, window=window)
     ref = flash_attention(q, k, v, use_pallas=False, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
 
@@ -119,7 +149,7 @@ def test_flash_attention_noncausal():
     q = jax.random.normal(ks[0], (1, 128, 2, 64))
     k = jax.random.normal(ks[1], (1, 128, 2, 64))
     v = jax.random.normal(ks[2], (1, 128, 2, 64))
-    out = flash_attention(q, k, v, q_block=64, kv_block=64, causal=False)
+    out = flash_attention(q, k, v, interpret=True, q_block=64, kv_block=64, causal=False)
     ref = flash_attention(q, k, v, use_pallas=False, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
 
@@ -142,7 +172,7 @@ def test_selective_scan_matches_ref(B, c, dI, N):
     Cm = jax.random.normal(ks[3], (B, c, N)) * 0.5
     A = -jnp.exp(jax.random.normal(ks[4], (dI, N)) * 0.3)
     h0 = jax.random.normal(ks[5], (B, dI, N)) * 0.1
-    y1, h1 = selective_scan(dt, x, Bm, Cm, A, h0)
+    y1, h1 = selective_scan(dt, x, Bm, Cm, A, h0, interpret=True)
     y2, h2 = selective_scan(dt, x, Bm, Cm, A, h0, use_pallas=False)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-4)
@@ -158,11 +188,13 @@ def test_selective_scan_state_chaining():
     Cm = jax.random.normal(ks[3], (B, c, N)) * 0.5
     A = -jnp.exp(jax.random.normal(ks[4], (dI, N)) * 0.3)
     h0 = jnp.zeros((B, dI, N))
-    y_full, h_full = selective_scan(dt, x, Bm, Cm, A, h0)
+    y_full, h_full = selective_scan(dt, x, Bm, Cm, A, h0,
+                                    interpret=True)
     h = h0
     ys = []
     for s in (slice(0, 16), slice(16, 32)):
-        y, h = selective_scan(dt[:, s], x[:, s], Bm[:, s], Cm[:, s], A, h)
+        y, h = selective_scan(dt[:, s], x[:, s], Bm[:, s], Cm[:, s], A, h,
+                              interpret=True)
         ys.append(y)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(ys, 1)),
                                np.asarray(y_full), atol=2e-4)
