@@ -9,8 +9,7 @@ checkpoint resumed and verified exact, publishing its us-per-batch ratio
 vs the monolithic dense-200 arm) and the ``chunked.overhead`` micro-arm
 (the same pre-materialized stream through the monolithic scan and the
 pipelined chunked driver; fails loudly when the ratio exceeds its
-guard).  ``--profile [DIR]`` wraps any run in a jax.profiler trace
-(TensorBoard/Perfetto viewable).  Suites that track a
+guard).  Suites that track a
 before/after perf trajectory additionally write structured numbers to
 BENCH_<suite>.json
 (vht -> BENCH_vht.json, amrules -> BENCH_amrules.json, clustream ->
@@ -54,12 +53,6 @@ def main() -> None:
                          "for the whole run and print the hit/miss split "
                          "at the end (second runs of the same suite skip "
                          "the XLA compiles)")
-    ap.add_argument("--profile", nargs="?", const="profile_trace",
-                    default=None, metavar="DIR",
-                    help="wrap the run in a jax.profiler trace written to "
-                         "DIR (default ./profile_trace; view with "
-                         "TensorBoard or Perfetto); combine with --only to "
-                         "profile one suite's arms")
     args = ap.parse_args()
     fast = args.fast or not args.full
 
@@ -102,27 +95,18 @@ def main() -> None:
             sys.exit(f"unknown suite {args.only!r} "
                      f"(available: {', '.join(suites)})")
         suites = {args.only: suites[args.only]}
-    import contextlib
-    profile_ctx = contextlib.nullcontext()
-    if args.profile:
-        import jax
-        profile_ctx = jax.profiler.trace(args.profile)
-
     print("name,us_per_call,derived")
     failed = set()
-    with profile_ctx:
-        for name, mod in suites.items():
-            try:
-                if args.sharded:
-                    mod.main(fast=fast, sharded=True)
-                else:
-                    mod.main(fast=fast)
-            except Exception as e:  # keep the harness going, flag the suite
-                failed.add(name)
-                print(f"{name}.SUITE_FAILED,0,{type(e).__name__}:{e}",
-                      flush=True)
-    if args.profile:
-        print(f"wrote jax.profiler trace under {args.profile}", flush=True)
+    for name, mod in suites.items():
+        try:
+            if args.sharded:
+                mod.main(fast=fast, sharded=True)
+            else:
+                mod.main(fast=fast)
+        except Exception as e:  # keep the harness going, flag the suite
+            failed.add(name)
+            print(f"{name}.SUITE_FAILED,0,{type(e).__name__}:{e}",
+                  flush=True)
     if args.compile_cache:
         from repro.runtime import compile_cache
         st = compile_cache.stats()

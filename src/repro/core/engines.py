@@ -35,6 +35,7 @@ from repro.core.topology import (Grouping, Topology, build_learner_topology)
 from repro.data.pipeline import Chunk, ChunkedStream
 from repro.distributed.sharding import (leading_axis_spec, mesh_context,
                                         mesh_spans_processes, put_global)
+from repro.runtime.telemetry import program
 
 
 class Engine:
@@ -297,7 +298,8 @@ class JitEngine(Engine):
         topology = self._as_topology(topology)
         key = id(topology)
         if key not in self._compiled:
-            self._compiled[key] = jax.jit(self._make_step(topology))
+            self._compiled[key] = program(self._make_step(topology),
+                                          "engine_step")
         with self._mesh_ctx():
             states, feedback, outputs = self._compiled[key](
                 carry["states"], carry["feedback"], source_payload)
@@ -320,7 +322,7 @@ class JitEngine(Engine):
 
             donate = (0,) if self.donate and \
                 jax.default_backend() != "cpu" else ()
-            fn = jax.jit(scan_fn, donate_argnums=donate)
+            fn = program(scan_fn, "stream_scan", donate_argnums=donate)
             self._compiled_scan[key] = fn
         return fn
 
@@ -423,7 +425,7 @@ class JitEngine(Engine):
 
             donate = (0,) if self.donate and \
                 jax.default_backend() != "cpu" else ()
-            fn = jax.jit(chunk_fn, donate_argnums=donate)
+            fn = program(chunk_fn, "chunk_masked", donate_argnums=donate)
             self._compiled_chunk[key] = fn
         return fn
 
@@ -470,7 +472,7 @@ class JitEngine(Engine):
 
             donate = (0,) if self.donate and \
                 jax.default_backend() != "cpu" else ()
-            fn = jax.jit(chunk_fn, donate_argnums=donate)
+            fn = program(chunk_fn, "chunk_program", donate_argnums=donate)
             self._compiled_chunk_full[key] = fn
         return fn
 
@@ -496,7 +498,7 @@ class JitEngine(Engine):
         if key not in self._compiled_boundary:
             fn = self._make_boundary(topology)
             self._compiled_boundary[key] = \
-                jax.jit(fn) if fn is not None else None
+                program(fn, "chunk_boundary") if fn is not None else None
         return self._compiled_boundary[key]
 
     def run_stream_chunked(self, topology: Topology, carry, chunks, *,

@@ -18,9 +18,11 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.topology import Task
 from repro.distributed.sharding import host_value
+from repro.runtime.telemetry import Histogram
 
 
 def stack_outputs(outs):
@@ -369,17 +371,22 @@ class _ChunkDrain:
         ev = self.ev
         if self.poisoned_at is not None:
             return                      # discard: the run is rolling back
-        if t.flag is not None:
-            if not bool(t.flag):        # the per-chunk sync, off hot path
-                with self._lock:
-                    self.poisoned_at = t.index
-                return
-        else:
-            jax.block_until_ready(t.done)
+        with TraceAnnotation("repro.drain.wait"):
+            if t.flag is not None:      # the per-chunk sync, off hot path
+                finite = bool(t.flag)
+            else:
+                finite = True
+                jax.block_until_ready(t.done)
+        if not finite:
+            with self._lock:
+                self.poisoned_at = t.index
+            return
         if t.pub_state is not None:
-            ev.publisher.publish(t.index, t.pub_state)
+            with TraceAnnotation("repro.publish"):
+                ev.publisher.publish(t.index, t.pub_state)
         if t.acc_fork is not None:
-            ev._save(t.index, t.carry, t.acc_fork)
+            with TraceAnnotation("repro.checkpoint.save"):
+                ev._save(t.index, t.carry, t.acc_fork)
         if ev.on_chunk is not None:
             ev.on_chunk(t.outs, t.chunk, t.carry)
         if ev.supervisor is not None:
@@ -392,6 +399,14 @@ class _ChunkDrain:
                 if newly:
                     self.known_dead |= newly
                     self.newly_dead |= newly
+
+
+#: the chunk loop's host stages: each is a ``repro.chunk.<stage>`` span and
+#: a histogram of milliseconds per chunk in the run's ``report["stages"]``
+#: -- taking the next chunk from the stream, the engine call through the
+#: ticket, and the wait for a free in-flight slot (pipelined driver only)
+PIPELINED_STAGES = ("stream_wait", "dispatch", "backpressure")
+SYNC_STAGES = PIPELINED_STAGES[:2]
 
 
 class ChunkedPrequentialEvaluation(Task):
@@ -448,7 +463,16 @@ class ChunkedPrequentialEvaluation(Task):
     checkpoint manifests, same kill/poison/elastic semantics -- the
     synchronous driver survives as the oracle and for debugging (see
     benchmarks/README.md).
+
+    Each run's ``report["stages"]`` holds its stage table
+    (``PIPELINED_STAGES``; the synchronous driver has no backpressure):
+    a ``telemetry.Histogram`` snapshot of milliseconds per chunk and
+    ``total_s``.  The newest finished run's
+    table is also ``ChunkedPrequentialEvaluation.last_stages``, for a
+    reader in the same process; each finished run replaces it.
     """
+
+    last_stages: dict | None = None
 
     def __init__(self, learner, stream, *, engine=None,
                  checkpoint=None, checkpoint_every: int = 1, key=None,
@@ -611,9 +635,11 @@ class ChunkedPrequentialEvaluation(Task):
         return carry, start, acc, seen0, check
 
     def _epilogue(self, carry, acc, report, *, t0, timed, seen0, start,
-                  end) -> PrequentialResult:
+                  end, stages) -> PrequentialResult:
         """Shared run teardown: final fence, throughput, pending-writer
-        fences (checkpoint, async publisher), source-retry accounting."""
+        fences (checkpoint, async publisher), source-retry accounting, and
+        the run's stage table (``report["stages"]``, also left in
+        ``ChunkedPrequentialEvaluation.last_stages``)."""
         jax.block_until_ready(jax.tree.leaves(carry)[0])
         t_end = time.perf_counter()
         wall = max(t_end - t0, 1e-9)
@@ -643,6 +669,9 @@ class ChunkedPrequentialEvaluation(Task):
             from repro.runtime import compile_cache
             report["compile_cache"] = dict(
                 dir=str(self.compile_cache_dir), **compile_cache.stats())
+        report["stages"] = {name: dict(h.snapshot(), total_s=h.sum / 1e3)
+                            for name, h in stages.items()}
+        ChunkedPrequentialEvaluation.last_stages = report["stages"]
         return PrequentialResult(
             metric=acc.metric, throughput=thr, curve=acc.curve,
             extra={"carry": carry, "seen": acc.seen,
@@ -690,38 +719,50 @@ class ChunkedPrequentialEvaluation(Task):
         end = self.stream.n_chunks
         cursor = start
 
+        stages = {name: Histogram() for name in SYNC_STAGES}
+        wait_h, dispatch_h = stages.values()
+
         t0 = time.perf_counter()
         while cursor < end:
             poisoned_at = None
             it = iter(self.stream.starting_at(cursor))
+            t_free = time.perf_counter()
             try:
-                for chunk in it:
+                while True:
+                    with TraceAnnotation("repro.chunk.stream_wait"):
+                        chunk = next(it, None)
+                    if chunk is None:
+                        break
                     if chunk.index in skip:
                         report["events"].append(("skip", chunk.index))
                         cursor = chunk.index + 1
                         continue
                     tc = time.perf_counter()
-                    if self.injector is not None:
-                        # straggler injection: the sleep lands inside the
-                        # timed region so the supervisor's heartbeat sees
-                        # the slow chunk
-                        self.injector.maybe_delay(chunk.index)
-                    carry, outs = self.engine.run_stream_chunked(
-                        learner, carry, [chunk],
-                        reduce_outputs=(_metrics_only
-                                        if self.on_chunk is None else None))
-                    if self.injector is not None:
-                        # models "this chunk's compute blew up": the NaN
-                        # lands in the post-chunk carry, where the boundary
-                        # finite-check must catch it
-                        carry = self.injector.maybe_poison(chunk.index,
-                                                           carry)
-                    if check and not carry_all_finite(carry):
-                        poisoned_at = chunk.index
-                        break
-                    if self.injector is not None:
-                        self.injector.maybe_kill(chunk.index)
-                    acc.update(outs["metrics"])
+                    wait_h.add((tc - t_free) * 1e3)
+                    with TraceAnnotation("repro.chunk.dispatch"):
+                        if self.injector is not None:
+                            # straggler injection: the sleep lands inside
+                            # the timed region so the supervisor's
+                            # heartbeat sees the slow chunk
+                            self.injector.maybe_delay(chunk.index)
+                        carry, outs = self.engine.run_stream_chunked(
+                            learner, carry, [chunk],
+                            reduce_outputs=(_metrics_only
+                                            if self.on_chunk is None
+                                            else None))
+                        if self.injector is not None:
+                            # models "this chunk's compute blew up": the
+                            # NaN lands in the post-chunk carry, where the
+                            # boundary finite-check must catch it
+                            carry = self.injector.maybe_poison(chunk.index,
+                                                               carry)
+                        if check and not carry_all_finite(carry):
+                            poisoned_at = chunk.index
+                            break
+                        if self.injector is not None:
+                            self.injector.maybe_kill(chunk.index)
+                        acc.update(outs["metrics"])
+                    dispatch_h.add((time.perf_counter() - tc) * 1e3)
                     if not timed:
                         jax.block_until_ready(jax.tree.leaves(carry)[0])
                         timed.append((time.perf_counter(),
@@ -733,11 +774,13 @@ class ChunkedPrequentialEvaluation(Task):
                         # re-validates (finiteness + manifest structure
                         # round-trip) before readers see anything
                         from repro.serving.snapshot import model_state_of
-                        self.publisher.publish(chunk.index,
-                                               model_state_of(carry))
+                        with TraceAnnotation("repro.publish"):
+                            self.publisher.publish(chunk.index,
+                                                   model_state_of(carry))
                     if self.checkpoint is not None \
                             and (chunk.index + 1) % every == 0:
-                        self._save(chunk.index, carry, acc)
+                        with TraceAnnotation("repro.checkpoint.save"):
+                            self._save(chunk.index, carry, acc)
                     if self.on_chunk is not None:
                         self.on_chunk(outs, chunk, carry)
                     cursor = chunk.index + 1
@@ -752,6 +795,7 @@ class ChunkedPrequentialEvaluation(Task):
                             carry = self._elastic_replace(
                                 cursor, carry, acc, report, newly_dead)
                             break   # re-enter from cursor on the new mesh
+                    t_free = time.perf_counter()
             finally:
                 close = getattr(it, "close", None)
                 if close is not None:
@@ -761,7 +805,8 @@ class ChunkedPrequentialEvaluation(Task):
                     poisoned_at, skip, retries, report, key0)
 
         return self._epilogue(carry, acc, report, t0=t0, timed=timed,
-                              seen0=seen0, start=start, end=end)
+                              seen0=seen0, start=start, end=end,
+                              stages=stages)
 
     def _run_pipelined(self, *, resume: bool = True) -> PrequentialResult:
         """Free-running chunk driver: dispatch chunk k+1 while the device
@@ -798,6 +843,9 @@ class ChunkedPrequentialEvaluation(Task):
         end = self.stream.n_chunks
         cursor = start
 
+        stages = {name: Histogram() for name in PIPELINED_STAGES}
+        wait_h, dispatch_h, backpressure_h = stages.values()
+
         t0 = time.perf_counter()
         drain = _ChunkDrain(self, report, check, self.max_inflight_chunks,
                             self._dead_hosts())
@@ -805,8 +853,13 @@ class ChunkedPrequentialEvaluation(Task):
             while cursor < end:
                 poisoned_local = None
                 it = iter(self.stream.starting_at(cursor))
+                t_free = time.perf_counter()
                 try:
-                    for chunk in it:
+                    while True:
+                        with TraceAnnotation("repro.chunk.stream_wait"):
+                            chunk = next(it, None)
+                        if chunk is None:
+                            break
                         if drain.has_event():
                             break    # fence: rollback/re-place/error pending
                         if chunk.index in skip:
@@ -814,58 +867,76 @@ class ChunkedPrequentialEvaluation(Task):
                             cursor = chunk.index + 1
                             continue
                         tc = time.perf_counter()
-                        if inj is not None:
-                            inj.maybe_delay(chunk.index)
-                        carry, outs = self.engine.run_stream_chunked(
-                            learner, carry, [chunk], reduce_outputs=reducer)
-                        if inj is not None:
-                            carry = inj.maybe_poison(chunk.index, carry)
-                        flag = carry_finite_flag(carry) if check else None
-                        if (inj is not None and inj.kill_at_chunk is not None
-                                and not inj.killed
-                                and int(chunk.index) == int(inj.kill_at_chunk)):
-                            # kill fence: drain everything first so exactly
-                            # the checkpoints a synchronous run would have
-                            # issued are on disk, then replicate the sync
-                            # ordering (earlier poison > own finite check >
-                            # kill) before dying
-                            drain.flush()
-                            if drain.poisoned_at is not None:
-                                break
-                            if flag is not None and not bool(flag):
-                                poisoned_local = chunk.index
-                                break
-                            inj.maybe_kill(chunk.index)
-                        acc.update(outs["metrics"])
-                        save_due = (self.checkpoint is not None
-                                    and (chunk.index + 1) % every == 0)
-                        # fork BEFORE dispatching the next chunk: the
-                        # snapshot covers exactly chunks <= this one, no
-                        # matter when the drain's flush happens
-                        acc_fork = acc.fork() if save_due else None
-                        t_carry = carry
-                        if donating and (save_due or self.on_chunk is not None
-                                         or self.publisher is not None):
-                            t_carry = jax.tree.map(jnp.array, carry)
-                        drain.submit(_ChunkTicket(
-                            index=chunk.index,
-                            done=jax.tree.leaves(outs["metrics"])[0],
-                            flag=flag,
-                            carry=t_carry,
-                            outs=outs if self.on_chunk is not None else None,
-                            chunk=chunk if self.on_chunk is not None else None,
-                            pub_state=(model_state_of(t_carry)
-                                       if self.publisher is not None
-                                       else None),
-                            acc_fork=acc_fork,
-                            t_start=tc))
+                        wait_h.add((tc - t_free) * 1e3)
+                        with TraceAnnotation("repro.chunk.dispatch"):
+                            if inj is not None:
+                                inj.maybe_delay(chunk.index)
+                            carry, outs = self.engine.run_stream_chunked(
+                                learner, carry, [chunk],
+                                reduce_outputs=reducer)
+                            if self.publisher is not None:
+                                self.publisher.issued_cursor = chunk.index
+                            if inj is not None:
+                                carry = inj.maybe_poison(chunk.index, carry)
+                            flag = carry_finite_flag(carry) if check else None
+                            if (inj is not None
+                                    and inj.kill_at_chunk is not None
+                                    and not inj.killed
+                                    and int(chunk.index)
+                                    == int(inj.kill_at_chunk)):
+                                # kill fence: drain everything first so
+                                # exactly the checkpoints a synchronous run
+                                # would have issued are on disk, then
+                                # replicate the sync ordering (earlier
+                                # poison > own finite check > kill) before
+                                # dying
+                                drain.flush()
+                                if drain.poisoned_at is not None:
+                                    break
+                                if flag is not None and not bool(flag):
+                                    poisoned_local = chunk.index
+                                    break
+                                inj.maybe_kill(chunk.index)
+                            acc.update(outs["metrics"])
+                            save_due = (self.checkpoint is not None
+                                        and (chunk.index + 1) % every == 0)
+                            # fork BEFORE dispatching the next chunk: the
+                            # snapshot covers exactly chunks <= this one,
+                            # no matter when the drain's flush happens
+                            acc_fork = acc.fork() if save_due else None
+                            t_carry = carry
+                            if donating and (save_due
+                                             or self.on_chunk is not None
+                                             or self.publisher is not None):
+                                t_carry = jax.tree.map(jnp.array, carry)
+                            on_chunk = self.on_chunk is not None
+                            ticket = _ChunkTicket(
+                                index=chunk.index,
+                                done=jax.tree.leaves(outs["metrics"])[0],
+                                flag=flag,
+                                carry=t_carry,
+                                outs=outs if on_chunk else None,
+                                chunk=chunk if on_chunk else None,
+                                pub_state=(model_state_of(t_carry)
+                                           if self.publisher is not None
+                                           else None),
+                                acc_fork=acc_fork,
+                                t_start=tc)
+                        t_submit = time.perf_counter()
+                        dispatch_h.add((t_submit - tc) * 1e3)
+                        with TraceAnnotation("repro.chunk.backpressure"):
+                            drain.submit(ticket)
+                        t_free = time.perf_counter()
+                        backpressure_h.add((t_free - t_submit) * 1e3)
                         cursor = chunk.index + 1
                         if not timed:
                             # compile-exclusion timestamp (same as sync):
-                            # the only steady-state sync, and only once
+                            # the only steady-state sync, and only once; no
+                            # stage's time
                             jax.block_until_ready(jax.tree.leaves(carry)[0])
                             timed.append((time.perf_counter(),
                                           float(np.sum(acc.seen))))
+                            t_free = timed[0][0]
                 finally:
                     close = getattr(it, "close", None)
                     if close is not None:
@@ -890,4 +961,5 @@ class ChunkedPrequentialEvaluation(Task):
             drain.stop()
 
         return self._epilogue(carry, acc, report, t0=t0, timed=timed,
-                              seen0=seen0, start=start, end=end)
+                              seen0=seen0, start=start, end=end,
+                              stages=stages)

@@ -25,6 +25,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.generators import bin_numeric
 from repro.distributed.sharding import spans_processes
@@ -332,16 +333,19 @@ class ChunkedStream:
 
         try:
             for i in range(self.start_chunk, self.n_chunks):
-                chunk = _pad_chunk(i, self._fetch_retry(i), self.chunk_len)
-                if self.to_device:
-                    # async host->device copy of chunk k+1 overlaps chunk
-                    # k's compute (device_put returns immediately); leaves
-                    # a generator already committed with the right
-                    # placement are passed through untouched
-                    chunk = dataclasses.replace(
-                        chunk, payload=jax.tree.map(
-                            lambda x: _place(x, self.sharding),
-                            chunk.payload))
+                with TraceAnnotation("repro.stream.produce"):
+                    chunk = _pad_chunk(i, self._fetch_retry(i),
+                                       self.chunk_len)
+                    if self.to_device:
+                        # async host->device copy of chunk k+1 overlaps
+                        # chunk k's compute (device_put returns
+                        # immediately); leaves a generator already
+                        # committed with the right placement are passed
+                        # through untouched
+                        chunk = dataclasses.replace(
+                            chunk, payload=jax.tree.map(
+                                lambda x: _place(x, self.sharding),
+                                chunk.payload))
                 if not put(chunk):
                     return
             put(None)

@@ -102,8 +102,10 @@ def _kernel(seg_ref, mom_ref, xbin_ref, stats_in_ref, stats_ref, *,
 
 
 def rule_stats_pallas(stats, seg, xbin, mom, *, attr_tile: int = 0,
-                      interpret: bool = False):
-    """stats: [R, m, bins, C]; returns updated stats (aliased in-place)."""
+                      interpret: bool = False,
+                      name: str = "rule_stats_update"):
+    """stats: [R, m, bins, C]; returns updated stats (aliased in-place).
+    ``name`` is the kernel's name in compiled programs and traces."""
     R, m, nb, C = stats.shape
     B = seg.shape[0]
     group = nb * C
@@ -130,6 +132,7 @@ def rule_stats_pallas(stats, seg, xbin, mom, *, attr_tile: int = 0,
         input_output_aliases={3: 0},                       # stats aliased
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name=name,
     )(seg.astype(i32)[None], mom.astype(f32).T, xbin.astype(i32).T,
       stats.reshape(R, mp * group))
     out = out.reshape(R, mp, nb, C)

@@ -107,5 +107,6 @@ def split_gain_pallas(stats, *, node_tile: int = 0, col_tile: int = 0,
         out_shape=jax.ShapeDtypeStruct((N, m * nb), f32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="split_gain",
     )(stats.astype(f32).reshape(N, W))
     return out.reshape(N, m, nb)

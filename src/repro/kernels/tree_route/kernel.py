@@ -87,5 +87,6 @@ def tree_route_pallas(split_attr, split_bin, children, xbin, max_depth: int,
         out_shape=jax.ShapeDtypeStruct((M, 1, Bp), i32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="tree_route",
     )(tables.astype(i32), xbin_t.astype(i32))
     return out[:, 0, :B]

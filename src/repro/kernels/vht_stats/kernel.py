@@ -27,4 +27,4 @@ def stats_update_pallas(stats, leaf, xbin, y, w, *, attr_tile: int = 0,
     """stats: [N, m, bins, C]; returns updated stats (aliased in-place)."""
     mom = jax.nn.one_hot(y, stats.shape[3], dtype=f32) * w.astype(f32)[:, None]
     return rule_stats_pallas(stats, leaf, xbin, mom, attr_tile=attr_tile,
-                             interpret=interpret)
+                             interpret=interpret, name="vht_stats_update")

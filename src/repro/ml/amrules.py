@@ -134,6 +134,7 @@ def init_rules(rc: RulesConfig):
     }
 
 
+@jax.named_scope("route")
 def coverage(state, xbin, rc: RulesConfig):
     """[B, R] bool: does rule r cover instance b?
 
@@ -334,6 +335,7 @@ class AMRules:
 
     # ------------------------------------------------------------ pieces
 
+    @jax.named_scope("stats_update")
     def _scatter_stats(self, state, covered, first, xbin, mom):
         """Scatter (w, w*y, w*y^2) into the rule AND default-rule moment
         tensors.  The fused path runs ONE kernelized scatter over an
@@ -386,6 +388,7 @@ class AMRules:
         state["n_removed"] = state["n_removed"] + drift.sum().astype(i32)
         return state
 
+    @jax.named_scope("split_check")
     def _gated_decision(self, stats, gate):
         """The SDR cumsum + top-k over [..., m, bins] runs only when `gate`
         holds -- exact, because the caller consumes the decision exclusively
@@ -440,6 +443,7 @@ class AMRules:
                                state["pend_bin"], state["pend_op"],
                                bins_are_pending=True)
 
+    @jax.named_scope("split_apply")
     def _do_expand(self, state, expand, attr, tbin, op, bins_are_pending=False):
         rc = self.rc
         state = dict(state)

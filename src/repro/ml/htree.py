@@ -86,6 +86,7 @@ def init_tree(tc: TreeConfig):
 # routing (model aggregator: sort instance to leaf -- Alg. 1 line 1)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("route")
 def route(state, xbin, tc: TreeConfig):
     """xbin: [B, m] int32 binned attributes -> leaf ids [B].
 
@@ -122,6 +123,7 @@ def predict(state, xbin, tc: TreeConfig):
 # statistics update (LS processors: Alg. 2)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("stats_update")
 def update_stats(state, leaf, xbin, y, w, tc: TreeConfig):
     """Accumulate n_ijk for a micro-batch.  w: [B] weights (0 = dropped).
 
@@ -235,6 +237,7 @@ def gated_check(n_due, k, gathered, full, idle, operand):
         idle, operand)
 
 
+@jax.named_scope("split_check")
 def decide_splits(state, tc: TreeConfig):
     """MA Receive(local_result): top-2 across attributes, Hoeffding test.
 
@@ -271,6 +274,7 @@ def decide_splits(state, tc: TreeConfig):
                        lambda s: _decide_splits_impl(s, tc), idle, state)
 
 
+@jax.named_scope("split_apply")
 def apply_splits(state, split_mask, best_attr, best_bin, tc: TreeConfig,
                  child_counts=None):
     """Replace chosen leaves by split nodes, allocate 2 children each

@@ -243,6 +243,14 @@ class _ChaosPublisher:
     def __getattr__(self, name):
         return getattr(self._publisher, name)
 
+    @property
+    def issued_cursor(self) -> int:
+        return self._publisher.issued_cursor
+
+    @issued_cursor.setter
+    def issued_cursor(self, chunk_index: int):
+        self._publisher.issued_cursor = chunk_index
+
 
 def request_burst(server, xs, *, deadline_ms: float | None = None):
     """Fire one request per row of `xs` back-to-back (no pacing) -- the

@@ -41,6 +41,7 @@ from repro.ml.amrules import AMRules, HAMR
 from repro.ml.clustream import CluStream
 from repro.ml.ensemble import OzaEnsemble
 from repro.ml.vht import VHT
+from repro.runtime.telemetry import program
 
 f32 = jnp.float32
 
@@ -123,7 +124,7 @@ def make_predict_fn(learner, *, jit: bool = True):
         raise TypeError(
             f"no predict-only fast path for {type(learner).__name__}; "
             "expected VHT, OzaEnsemble, AMRules/VAMR/HAMR, or CluStream")
-    return jax.jit(fn) if jit else fn
+    return program(fn, "serve_predict") if jit else fn
 
 
 def reference_predict(learner, state, x, tenant=None):

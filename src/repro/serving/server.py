@@ -29,7 +29,16 @@ recast as a serving system, hardened the way PR 6 hardened training:
   * **graceful degradation** -- answers carry the snapshot version, its
     staleness in chunks, and the publisher's ``degraded`` flag, so a
     stalled or circuit-broken publisher yields stale-but-finite answers
-    that SAY they are stale, never silence and never garbage.
+    that SAY they are stale, never silence and never garbage;
+  * **stage counters** -- ``status()`` carries three histograms
+    (``repro.runtime.telemetry``): ``queue_ms``, each answered request's
+    wait from submission to its batch's formation; ``device_ms``, each
+    batch's time from formation to its answers on the host; and
+    ``chunks_ahead``, the training chunks issued but not yet drained
+    when each batch's predict was issued -- at most that many chunk
+    programs are ahead of it on the device.  All come from clock reads
+    the dispatcher makes anyway, and are added under the lock the
+    accounting takes.
 """
 
 from __future__ import annotations
@@ -42,7 +51,9 @@ from typing import Any
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.runtime.telemetry import Histogram
 from repro.serving.predict import make_predict_fn
 
 
@@ -120,6 +131,9 @@ class ModelServer:
         self.batches = 0
         self.max_queue_depth = 0
         self.degraded_answers = 0
+        self.queue_ms = Histogram()
+        self.device_ms = Histogram()
+        self.chunks_ahead = Histogram()
         if start:
             self.start()
 
@@ -218,15 +232,16 @@ class ModelServer:
             except queue.Empty:
                 continue
             batch = [first]
-            opened = self._clock()
-            while len(batch) < self.cfg.max_batch:
-                left = wait_s - (self._clock() - opened)
-                if left <= 0:
-                    break
-                try:
-                    batch.append(self._q.get(timeout=left))
-                except queue.Empty:
-                    break
+            with TraceAnnotation("repro.serve.batch_open"):
+                opened = self._clock()
+                while len(batch) < self.cfg.max_batch:
+                    left = wait_s - (self._clock() - opened)
+                    if left <= 0:
+                        break
+                    try:
+                        batch.append(self._q.get(timeout=left))
+                    except queue.Empty:
+                        break
             self._serve_batch(batch)
 
     def _serve_batch(self, batch):
@@ -251,19 +266,26 @@ class ModelServer:
             # the same predict program and garbage could trip finiteness
             # asserts); padded outputs are simply dropped
             xs = np.concatenate([xs, np.repeat(xs[-1:], pad, axis=0)], 0)
-        if self._fleet is not None:
-            ts = np.asarray([r.tenant for r in live], np.int32)
-            if pad:
-                ts = np.concatenate([ts, np.repeat(ts[-1:], pad)], 0)
-            preds = np.asarray(self._fn(snap.state, jnp.asarray(xs),
-                                        jnp.asarray(ts)))
-        else:
-            preds = np.asarray(self._fn(snap.state, jnp.asarray(xs)))
+        # chunks the training loop issued and has not drained: at most
+        # what this predict queues behind (unlocked reads of two counters)
+        ahead = max(0, self.publisher.issued_cursor
+                    - self.publisher.train_cursor)
+        with TraceAnnotation("repro.serve.predict"):
+            if self._fleet is not None:
+                ts = np.asarray([r.tenant for r in live], np.int32)
+                if pad:
+                    ts = np.concatenate([ts, np.repeat(ts[-1:], pad)], 0)
+                preds = np.asarray(self._fn(snap.state, jnp.asarray(xs),
+                                            jnp.asarray(ts)))
+            else:
+                preds = np.asarray(self._fn(snap.state, jnp.asarray(xs)))
         stale = max(0, self.publisher.train_cursor - snap.chunk_index)
         degraded = self.publisher.degraded()
         done = self._clock()
         with self._lock:
             self.batches += 1
+            self.device_ms.add((done - now) * 1e3)
+            self.chunks_ahead.add(ahead)
         for i, r in enumerate(live):
             r.pred = preds[i]
             r.meta = {
@@ -276,18 +298,20 @@ class ModelServer:
             }
             if r.tenant is not None:
                 r.meta["tenant"] = r.tenant
-            self._finish(r, ANSWERED)
+            self._finish(r, ANSWERED, queue_ms=(now - r.submitted_at) * 1e3)
             if degraded:
                 with self._lock:
                     self.degraded_answers += 1
 
-    def _finish(self, r: Request, status: str, *, reason: str | None = None):
+    def _finish(self, r: Request, status: str, *, reason: str | None = None,
+                queue_ms: float = 0.0):
         r.status = status
         if reason is not None:
             r.meta = dict(r.meta, reason=reason)
         with self._lock:
             if status == ANSWERED:
                 self.answered += 1
+                self.queue_ms.add(queue_ms)
             elif status == SHED:
                 self.shed += 1
             elif status == OVERLOADED:
@@ -315,6 +339,9 @@ class ModelServer:
                 "degraded_answers": self.degraded_answers,
                 "queue_limit": self.cfg.queue_limit,
                 "accounting_ok": pending >= 0,
+                "queue_ms": self.queue_ms.snapshot(),
+                "device_ms": self.device_ms.snapshot(),
+                "chunks_ahead": self.chunks_ahead.snapshot(),
             }
         out.update({f"publisher_{k}": v
                     for k, v in self.publisher.status().items()})

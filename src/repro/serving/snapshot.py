@@ -28,7 +28,11 @@ any moment.  ``SnapshotPublisher`` is the boundary between the two worlds:
     even when nothing is published, so a stalled publisher shows up as
     ``staleness()`` growing past ``max_staleness_chunks`` and the
     ``degraded`` readiness flag flipping -- the server keeps answering
-    from last-good, it just stops claiming freshness.
+    from last-good, it just stops claiming freshness;
+  * the training loop also writes ``issued_cursor``, the newest chunk it
+    has issued to the device; ``issued_cursor - train_cursor`` is the
+    chunks issued but not yet drained, which the server reads to bound
+    how many chunk programs a predict queues behind.
 """
 
 from __future__ import annotations
@@ -107,6 +111,7 @@ class SnapshotPublisher:
         self._lock = threading.Lock()
         self._current: Snapshot | None = None
         self.train_cursor = -1         # newest chunk boundary observed
+        self.issued_cursor = -1        # newest chunk the trainer issued
         self.published = 0
         self.rejected_snapshots = 0
         self.consecutive_rejections = 0

@@ -369,6 +369,9 @@ def test_fleet_server_routes_requests_to_their_tenant():
     srv = ModelServer(fleet, pub, ServeConfig(max_batch=4, max_wait_ms=1.0))
     try:
         xs = _tenant_xy(0)[0][5][:4]
+        # the first predict compiles the fleet predict program: wait for
+        # it apart, so the checked requests' waits cover no compile
+        assert srv.submit(xs[0], tenant=0).result(300.0).status == "answered"
         tenants = [2, 0, 1, 2]
         reqs = [srv.submit(xs[i], tenant=f)
                 for i, f in enumerate(tenants)]
