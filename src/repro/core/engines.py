@@ -139,6 +139,17 @@ def _concat_outputs(segments):
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *segments)
 
 
+def _scan(layout, body, carry, xs):
+    """``jax.lax.scan`` with the carry held in the processors' scan layout
+    (``Processor.to_scan``) from the scan's entry to its exit; a plain scan
+    when ``layout`` is None."""
+    if layout is None:
+        return jax.lax.scan(body, carry, xs)
+    to_scan, from_scan = layout
+    carry, outs = jax.lax.scan(body, to_scan(carry), xs)
+    return from_scan(carry), outs
+
+
 class LocalEngine(Engine):
     """Sequential reference engine (paper: the local execution engine).
 
@@ -242,9 +253,14 @@ class JitEngine(Engine):
         self._compiled_chunk: dict[int, Callable] = {}
         self._compiled_chunk_full: dict[tuple, Callable] = {}
         self._compiled_boundary: dict[int, Callable | None] = {}
+        self._packs: dict[int, bool] = {}
+        # chunk programs run with a processor's state in a scan layout of
+        # its own (see ``_make_scan_layout``), counted over the engine's life
+        self.packed_chunks = 0
 
     def _evict_topology(self, topology: Topology):
         self._compiled.pop(id(topology), None)
+        self._packs.pop(id(topology), None)
         self._compiled_scan.pop(id(topology), None)
         self._compiled_chunk.pop(id(topology), None)
         self._compiled_boundary.pop(id(topology), None)
@@ -312,13 +328,14 @@ class JitEngine(Engine):
         fn = self._compiled_scan.get(key)
         if fn is None:
             step = self._make_step(topology)
+            layout = self._make_scan_layout(topology)
 
             def scan_fn(carry, payloads):
                 def body(c, payload):
                     states, fb, outs = step(c["states"], c["feedback"],
                                             payload)
                     return {"states": states, "feedback": fb}, outs
-                return jax.lax.scan(body, carry, payloads)
+                return _scan(layout, body, carry, payloads)
 
             donate = (0,) if self.donate and \
                 jax.default_backend() != "cpu" else ()
@@ -400,6 +417,7 @@ class JitEngine(Engine):
         fn = self._compiled_chunk.get(key)
         if fn is None:
             step = self._make_step(topology)
+            layout = self._make_scan_layout(topology)
 
             def chunk_fn(carry, payloads, valid):
                 out_sd = jax.eval_shape(
@@ -421,7 +439,7 @@ class JitEngine(Engine):
 
                     return jax.lax.cond(v, live, dead, c)
 
-                return jax.lax.scan(body, carry, (payloads, valid))
+                return _scan(layout, body, carry, (payloads, valid))
 
             donate = (0,) if self.donate and \
                 jax.default_backend() != "cpu" else ()
@@ -456,6 +474,7 @@ class JitEngine(Engine):
             step = self._make_step(topology)
             boundary = self._make_boundary(topology) if fused_boundary \
                 else None
+            layout = self._make_scan_layout(topology)
 
             def chunk_fn(carry, payloads):
                 def body(c, payload):
@@ -463,7 +482,7 @@ class JitEngine(Engine):
                                             payload)
                     return {"states": states, "feedback": fb}, outs
 
-                carry, outs = jax.lax.scan(body, carry, payloads)
+                carry, outs = _scan(layout, body, carry, payloads)
                 if boundary is not None:
                     carry = boundary(carry)
                 if reducer is not None:
@@ -475,6 +494,36 @@ class JitEngine(Engine):
             fn = program(chunk_fn, "chunk_program", donate_argnums=donate)
             self._compiled_chunk_full[key] = fn
         return fn
+
+    def _make_scan_layout(self, topology: Topology):
+        """``(to_scan, from_scan)`` over the carry: every processor's scan
+        layout hooks applied to its state, which the scanned programs hold
+        in that layout from their entry to their exit.  None when no
+        processor has them: the programs are then the plain scan."""
+        hooks = {n: (p.to_scan, p.from_scan)
+                 for n, p in topology.processors.items()
+                 if p.to_scan is not None}
+        if not hooks:
+            return None
+
+        def apply(carry, side):
+            states = dict(carry["states"])
+            for name, pair in hooks.items():
+                states[name] = pair[side](states[name])
+            return {"states": states, "feedback": carry["feedback"]}
+
+        return (lambda carry: apply(carry, 0)), (lambda carry: apply(carry, 1))
+
+    def _packs_state(self, topology: Topology, carry) -> bool:
+        """Whether the scan layout changes the form of any state leaf, so a
+        chunk program of this topology runs with its state packed."""
+        key = id(topology)
+        if key not in self._packs:
+            layout = self._make_scan_layout(topology)
+            shapes = lambda t: [x.shape for x in jax.tree.leaves(t)]
+            self._packs[key] = layout is not None and \
+                shapes(jax.eval_shape(layout[0], carry)) != shapes(carry)
+        return self._packs[key]
 
     def _make_boundary(self, topology: Topology):
         """The chunk-boundary phase: apply every processor's ``boundary``
@@ -576,6 +625,8 @@ class JitEngine(Engine):
                         carry, payloads, valid)
                     if reducer is not None:
                         outs = reducer(outs)
+            if self._packs_state(topology, carry):
+                self.packed_chunks += 1
             segments.append(outs)
         outs = _concat_outputs(segments)
         if chunk.padded:
@@ -670,6 +721,13 @@ class ShardMapEngine(JitEngine):
                 fb, outputs
 
         return step
+
+    def _make_scan_layout(self, topology: Topology):
+        # no scan layout under a mesh: every step re-constrains the hinted
+        # leaves (``_apply_hints``), and a packed leaf would meet hints
+        # written for its usual form -- e.g. the VHT statistics' attribute
+        # split P(None, "model", None, None) on a 2-D array
+        return None
 
     def _mesh_ctx(self):
         # mesh_context also publishes the mesh through active_mesh(), which

@@ -470,6 +470,9 @@ class ChunkedPrequentialEvaluation(Task):
     ``total_s``.  The newest finished run's
     table is also ``ChunkedPrequentialEvaluation.last_stages``, for a
     reader in the same process; each finished run replaces it.
+    ``report["packed_chunks"]`` counts the chunk programs that ran with a
+    processor's state in its scan layout (``Processor.to_scan``: the
+    VHT's statistics packed 2-D), so a run shows whether that engaged.
     """
 
     last_stages: dict | None = None
@@ -702,7 +705,8 @@ class ChunkedPrequentialEvaluation(Task):
     def _run_sync(self, *, resume: bool = True) -> PrequentialResult:
         learner = self.learner
         report = {"events": [], "skipped_chunks": [], "rollbacks": 0,
-                  "remeshes": 0, "heartbeats": 0, "source_retries": []}
+                  "remeshes": 0, "heartbeats": 0, "source_retries": [],
+                  "packed_chunks": 0}
         self.report = report
         key0 = self.key
         carry, start, acc, seen0, check = self._prologue(resume, report)
@@ -745,11 +749,14 @@ class ChunkedPrequentialEvaluation(Task):
                             # the timed region so the supervisor's
                             # heartbeat sees the slow chunk
                             self.injector.maybe_delay(chunk.index)
+                        packed0 = self.engine.packed_chunks
                         carry, outs = self.engine.run_stream_chunked(
                             learner, carry, [chunk],
                             reduce_outputs=(_metrics_only
                                             if self.on_chunk is None
                                             else None))
+                        report["packed_chunks"] += \
+                            self.engine.packed_chunks - packed0
                         if self.injector is not None:
                             # models "this chunk's compute blew up": the
                             # NaN lands in the post-chunk carry, where the
@@ -822,7 +829,8 @@ class ChunkedPrequentialEvaluation(Task):
         order, same failure ordering."""
         learner = self.learner
         report = {"events": [], "skipped_chunks": [], "rollbacks": 0,
-                  "remeshes": 0, "heartbeats": 0, "source_retries": []}
+                  "remeshes": 0, "heartbeats": 0, "source_retries": [],
+                  "packed_chunks": 0}
         self.report = report
         key0 = self.key
         carry, start, acc, seen0, check = self._prologue(resume, report)
@@ -871,9 +879,12 @@ class ChunkedPrequentialEvaluation(Task):
                         with TraceAnnotation("repro.chunk.dispatch"):
                             if inj is not None:
                                 inj.maybe_delay(chunk.index)
+                            packed0 = self.engine.packed_chunks
                             carry, outs = self.engine.run_stream_chunked(
                                 learner, carry, [chunk],
                                 reduce_outputs=reducer)
+                            report["packed_chunks"] += \
+                                self.engine.packed_chunks - packed0
                             if self.publisher is not None:
                                 self.publisher.issued_cursor = chunk.index
                             if inj is not None:

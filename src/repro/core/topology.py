@@ -56,6 +56,15 @@ class Processor:
     # HLO entirely).  ``None`` means no hook; engines skip the dispatch.
     boundary: Callable | None = None
 
+    # Optional scan layout: ``to_scan(state) -> state`` and its inverse
+    # ``from_scan``.  A scanned chunk program applies them once at its
+    # entry and once at its exit, so its steps carry the state in a form
+    # of the processor's choosing (the VHT's statistics packed 2-D, as
+    # its kernel takes them) while the state between chunks -- snapshots,
+    # checkpoints, the per-step engines -- keeps its usual form.
+    to_scan: Callable | None = None
+    from_scan: Callable | None = None
+
     def init_state(self, key):  # pragma: no cover - interface
         return {}
 
@@ -209,6 +218,10 @@ class LearnerProcessor(Processor):
         fn = getattr(learner, "boundary", None)
         if fn is not None:
             self.boundary = fn
+        # likewise the scan layout (both hooks or neither)
+        if hasattr(learner, "to_scan"):
+            self.to_scan = learner.to_scan
+            self.from_scan = learner.from_scan
 
     def init_state(self, key):
         return self.learner.init(key)

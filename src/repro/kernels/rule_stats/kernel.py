@@ -9,7 +9,8 @@ per-instance weighting with one kernel:
     delta[r, j, b, c] = sum_i seg1h[i, r] * bin1h[i, j, b] * mom[i, c]
 
 The statistics are handed over as their lane-dense 2-D view
-``[R, m*bins*C]`` (a reshape outside the kernel) and tiled into
+``[R, m*bins*C]`` -- a reshape around the kernel for a 4-D caller, none
+for a caller that holds them packed -- and tiled into
 ``(node tile, ja*bins*C)`` blocks whose column count is a multiple of
 128, so no block puts a small axis in the lane dimension and the kernel
 never reshapes.  Per block:
@@ -101,26 +102,55 @@ def _kernel(seg_ref, mom_ref, xbin_ref, stats_in_ref, stats_ref, *,
     stats_ref[...] = stats_in_ref[...] + delta
 
 
+def packs(m: int, group: int, attr_tile: int = 0) -> bool:
+    """Whether statistics of ``m`` attributes by ``group`` columns reach
+    the kernel unpadded at every batch size (see ``attr_tile_for``), so a
+    caller may hold them as the packed 2-D view ``[R, m*group]``."""
+    if attr_tile:
+        return m % min(attr_tile, m) == 0
+    step = math.lcm(8, 128 // math.gcd(128, group))
+    return m * group <= 128 or m % step == 0
+
+
 def rule_stats_pallas(stats, seg, xbin, mom, *, attr_tile: int = 0,
                       interpret: bool = False,
                       name: str = "rule_stats_update"):
-    """stats: [R, m, bins, C]; returns updated stats (aliased in-place).
-    ``name`` is the kernel's name in compiled programs and traces."""
+    """stats: [R, m, bins, C], or its packed 2-D view [R, m*bins*C] at a
+    width that ``packs``; returns updated stats of the same shape (aliased
+    in-place).  The packed view goes to the kernel with no reshape in or
+    out.  ``name`` is the kernel's name in compiled programs and traces."""
+    if stats.ndim == 2:
+        return _pallas_2d(stats, seg, xbin, mom, attr_tile=attr_tile,
+                          interpret=interpret, name=name)
     R, m, nb, C = stats.shape
-    B = seg.shape[0]
-    group = nb * C
-    ja = min(attr_tile or attr_tile_for(m, group, B), m)
+    ja = min(attr_tile or attr_tile_for(m, nb * C, seg.shape[0]), m)
     mp = -(-m // ja) * ja
     if mp != m:
         xbin = jnp.pad(xbin, ((0, 0), (0, mp - m)))
         stats = jnp.pad(stats, ((0, 0), (0, mp - m), (0, 0), (0, 0)))
+    out = _pallas_2d(stats.reshape(R, mp * nb * C), seg, xbin, mom,
+                     attr_tile=ja, interpret=interpret, name=name)
+    out = out.reshape(R, mp, nb, C)
+    return out[:, :m] if mp != m else out
+
+
+def _pallas_2d(stats, seg, xbin, mom, *, attr_tile, interpret, name):
+    R, W = stats.shape
+    B, m = xbin.shape
+    C = mom.shape[1]
+    nb = W // (m * C)
+    group = nb * C
+    ja = min(attr_tile or attr_tile_for(m, group, B), m)
+    if m % ja:
+        raise ValueError(f"{m} attributes need padding to tiles of {ja}: "
+                         "pass the statistics as [R, m, bins, C]")
     nt = node_tile_for(R, B)
     T = ja * group
 
     kern = functools.partial(_kernel, n_bins=nb, n_mom=C)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
-        grid=(mp // ja, -(-R // nt)),
+        grid=(m // ja, -(-R // nt)),
         in_specs=[
             pl.BlockSpec((1, B), lambda j, i: (0, 0)),     # seg
             pl.BlockSpec((C, B), lambda j, i: (0, 0)),     # moments^T
@@ -128,12 +158,9 @@ def rule_stats_pallas(stats, seg, xbin, mom, *, attr_tile: int = 0,
             pl.BlockSpec((nt, T), lambda j, i: (i, j)),    # stats in
         ],
         out_specs=pl.BlockSpec((nt, T), lambda j, i: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, mp * group), stats.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, W), stats.dtype),
         input_output_aliases={3: 0},                       # stats aliased
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name=name,
-    )(seg.astype(i32)[None], mom.astype(f32).T, xbin.astype(i32).T,
-      stats.reshape(R, mp * group))
-    out = out.reshape(R, mp, nb, C)
-    return out[:, :m] if mp != m else out
+    )(seg.astype(i32)[None], mom.astype(f32).T, xbin.astype(i32).T, stats)

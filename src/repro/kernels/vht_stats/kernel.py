@@ -22,9 +22,12 @@ from repro.kernels.rule_stats.kernel import rule_stats_pallas
 f32 = jnp.float32
 
 
-def stats_update_pallas(stats, leaf, xbin, y, w, *, attr_tile: int = 0,
-                        interpret: bool = False):
-    """stats: [N, m, bins, C]; returns updated stats (aliased in-place)."""
-    mom = jax.nn.one_hot(y, stats.shape[3], dtype=f32) * w.astype(f32)[:, None]
+def stats_update_pallas(stats, leaf, xbin, y, w, *, n_classes: int = 0,
+                        attr_tile: int = 0, interpret: bool = False):
+    """stats: [N, m, bins, C], or its packed view [N, m*bins*C] with
+    ``n_classes`` = C; returns updated stats of the same shape (aliased
+    in-place)."""
+    C = n_classes or stats.shape[3]
+    mom = jax.nn.one_hot(y, C, dtype=f32) * w.astype(f32)[:, None]
     return rule_stats_pallas(stats, leaf, xbin, mom, attr_tile=attr_tile,
                              interpret=interpret, name="vht_stats_update")

@@ -47,24 +47,28 @@ def stats_update_segment(stats, leaf, xbin, y, w):
 
 
 def stats_update(stats, leaf, xbin, y, w, *, impl: str = "auto",
-                 attr_tile: int = 0, interpret: bool = False):
+                 attr_tile: int = 0, interpret: bool = False,
+                 n_classes: int = 0):
     """Accumulate VHT sufficient statistics for a micro-batch.
 
-    impl="auto" picks Pallas on TPU and the segment-sum formulation
-    elsewhere; `attr_tile` overrides the Pallas kernel's heuristic
-    attribute tile; `interpret=True` runs the Pallas kernel body in
-    interpret mode (for validation off TPU).  Under a multi-device mesh
-    the kernel runs replicated inside a shard_map (``run_replicated``).
+    stats is [N, m, bins, C]; the Pallas kernel also takes its packed
+    row-major view [N, m*bins*C], given with ``n_classes`` = C, and
+    returns that shape.  impl="auto" picks Pallas on TPU and the
+    segment-sum formulation elsewhere; `attr_tile` overrides the Pallas
+    kernel's heuristic attribute tile; `interpret=True` runs the Pallas
+    kernel body in interpret mode (for validation off TPU).  Under a
+    multi-device mesh the kernel runs replicated inside a shard_map
+    (``run_replicated``).
     """
     return _stats_update(stats, leaf, xbin, y, w, impl=impl,
                          attr_tile=attr_tile, interpret=interpret,
-                         mesh=kernel_mesh())
+                         n_classes=n_classes, mesh=kernel_mesh())
 
 
-@partial(jax.jit,
-         static_argnames=("impl", "attr_tile", "interpret", "mesh"))
+@partial(jax.jit, static_argnames=("impl", "attr_tile", "interpret",
+                                   "n_classes", "mesh"))
 def _stats_update(stats, leaf, xbin, y, w, *, impl, attr_tile, interpret,
-                  mesh):
+                  n_classes, mesh):
     if impl == "auto":
         impl = default_impl()
     if impl == "onehot":
@@ -75,4 +79,5 @@ def _stats_update(stats, leaf, xbin, y, w, *, impl, attr_tile, interpret,
         raise ValueError(f"unknown stats impl {impl!r}")
     return run_replicated(
         partial(stats_update_pallas, attr_tile=attr_tile,
-                interpret=interpret), mesh, stats, leaf, xbin, y, w)
+                interpret=interpret, n_classes=n_classes),
+        mesh, stats, leaf, xbin, y, w)

@@ -12,6 +12,11 @@ every counter lives in the system (the paper's memory argument); the split
 criterion reduces over (bin, class) per attribute *in parallel across the
 attribute shards*, exactly like the LS processors of Figure 2.
 
+The tree functions also take the statistics as their packed row-major view
+``stats[node, attr*bins*C]`` -- the statistics kernel's own layout, which a
+scanned chunk program carries (``VHT.to_scan``) -- and pick the path from
+the array's rank: 2 is packed.  Both give the same numbers.
+
 Numeric attributes use histogram bins (the standard VFDT-with-histograms
 approximation of MOA's Gaussian estimators); categorical attributes map
 bins = categories and use one-vs-rest binary splits.
@@ -134,8 +139,10 @@ def update_stats(state, leaf, xbin, y, w, tc: TreeConfig):
     from repro.kernels.vht_stats.ops import stats_update
     clsoh = jax.nn.one_hot(y, tc.n_classes, dtype=f32) * w[:, None]
     state = dict(state)
-    state["stats"] = stats_update(state["stats"], leaf, xbin, y, w,
-                                  impl=tc.stats_impl, attr_tile=tc.attr_tile)
+    packed = state["stats"].ndim == 2
+    state["stats"] = stats_update(
+        state["stats"], leaf, xbin, y, w, impl=tc.stats_impl,
+        attr_tile=tc.attr_tile, n_classes=tc.n_classes if packed else 0)
     state["class_counts"] = state["class_counts"].at[leaf].add(clsoh)
     state["since_attempt"] = state["since_attempt"].at[leaf].add(w)
     state["n_total"] = state["n_total"].at[leaf].add(w)
@@ -164,8 +171,16 @@ def hoeffding_bound(n, tc: TreeConfig):
     return jnp.sqrt(tc.range_r ** 2 * math.log(1.0 / tc.delta) / (2.0 * jnp.maximum(n, 1.0)))
 
 
+def unpacked(stats, tc: TreeConfig):
+    """[R, m, bins, C] statistics from either layout (a packed view is
+    reshaped: a relayout, so only on paths that need the 4-D form)."""
+    if stats.ndim == 2:
+        return stats.reshape(-1, tc.n_attrs, tc.n_bins, tc.n_classes)
+    return stats
+
+
 def _decide_splits_impl(state, tc: TreeConfig):
-    gains = split_gains(state["stats"], tc)             # [N, m, bins]
+    gains = split_gains(unpacked(state["stats"], tc), tc)   # [N, m, bins]
     N, m, bins = gains.shape
     # paper (Alg. 3/4): compare the best TWO ATTRIBUTES -- adjacent bins of
     # one attribute have near-identical gain and would make DeltaG ~ 0
@@ -198,15 +213,45 @@ def due_topk(due, score, k):
     return jax.lax.top_k(jnp.where(due, score, -1.0), k)[1]
 
 
-def child_counts_from_stats(stats, best_attr, best_bin):
+def child_counts_from_stats(stats, best_attr, best_bin, tc=None):
     """Left/right child class distributions for the chosen (attr, bin)
     thresholds, derived from the statistics cumsum over the bin axis.
-    stats: [R, m, bins, C]; best_attr/best_bin: [R] -> ([R, C], [R, C])."""
+    stats: [R, m, bins, C], or the packed [R, m*bins*C] with ``tc``, of
+    which only each row's chosen attribute ([R, bins*C] columns) is
+    gathered and summed; best_attr/best_bin: [R] -> ([R, C], [R, C])."""
     rows = jnp.arange(stats.shape[0])
+    if stats.ndim == 2:
+        group = tc.n_bins * tc.n_classes
+        cols = jnp.maximum(best_attr, 0)[:, None] * group + jnp.arange(group)
+        cum = jnp.cumsum(jnp.take_along_axis(stats, cols, 1).reshape(
+            -1, tc.n_bins, tc.n_classes), axis=1)
+        left = cum[rows, jnp.maximum(best_bin, 0)]
+        return left, cum[:, -1] - left
     cum = jnp.cumsum(stats, axis=2)
     left = cum[rows, jnp.maximum(best_attr, 0), jnp.maximum(best_bin, 0)]
     right = cum[rows, jnp.maximum(best_attr, 0), -1] - left
     return left, right
+
+
+def _splitting_child_counts(stats, do, best_attr, best_bin, tc: TreeConfig):
+    """Child class distributions of the splitting rows ``do`` of packed
+    statistics, [N, C] each (rows not splitting hold filler).  When no
+    more rows split than the check tile holds -- always after a gathered
+    split check -- only those rows are gathered; else every row is."""
+    N = stats.shape[0]
+    K = min(tc.check_tile, N)
+
+    def few(_):
+        idx = due_topk(do, jnp.zeros((N,), f32), K)
+        left, right = child_counts_from_stats(stats[idx], best_attr[idx],
+                                              best_bin[idx], tc)
+        zero = jnp.zeros((N, tc.n_classes), f32)
+        return zero.at[idx].set(left), zero.at[idx].set(right)
+
+    return jax.lax.cond(
+        jnp.sum(do.astype(i32)) <= K, few,
+        lambda _: child_counts_from_stats(stats, best_attr, best_bin, tc),
+        None)
 
 
 def gather_decide_tile(flat_state, due, k, tc: TreeConfig,
@@ -222,7 +267,7 @@ def gather_decide_tile(flat_state, due, k, tc: TreeConfig,
     s_k, a_k, b_k = _decide_splits_impl(sub, tc)
     if not with_children:
         return idx, s_k, a_k, b_k
-    left_k, right_k = child_counts_from_stats(sub["stats"], a_k, b_k)
+    left_k, right_k = child_counts_from_stats(sub["stats"], a_k, b_k, tc)
     return idx, s_k, a_k, b_k, left_k, right_k
 
 
@@ -249,7 +294,9 @@ def decide_splits(state, tc: TreeConfig):
                                   only attempted leaves can split
       * <= check_tile leaves due -> gather just those rows (top_k on the
                                   grace counter) and reduce [K, m, bins, C]
-                                  instead of [N, m, bins, C]; non-gathered
+                                  instead of [N, m, bins, C] (packed
+                                  statistics: [K, m*bins*C] rows, reshaped
+                                  as a tile); non-gathered
                                   nodes cannot split, and best_attr/bin are
                                   consumed only where should_split holds
       * more due than the tile -> fall back to the full reduction
@@ -317,9 +364,12 @@ def _apply_splits_impl(state, split_mask, best_attr, best_bin, tc: TreeConfig,
     # initialize children class counts from the split distribution
     if child_counts is not None:
         left_cnt, right_cnt = child_counts
+    elif state["stats"].ndim == 2:
+        left_cnt, right_cnt = _splitting_child_counts(
+            state["stats"], do, best_attr, best_bin, tc)
     else:
-        left_cnt, right_cnt = child_counts_from_stats(state["stats"],
-                                                      best_attr, best_bin)
+        left_cnt, right_cnt = child_counts_from_stats(
+            state["stats"], best_attr, best_bin)
 
     # scratch-row scatter: rows not splitting write to a throwaway slot N
     l_idx = jnp.where(do, jnp.clip(lchild, 0, N - 1), N)
@@ -343,8 +393,8 @@ def _apply_splits_impl(state, split_mask, best_attr, best_bin, tc: TreeConfig,
     # the broadcast 'drop' event instead
     if "stats" in state:
         zero = jnp.zeros_like(state["stats"][0])
-        state["stats"] = jnp.where(do[:, None, None, None], zero[None],
-                                   state["stats"])
+        rows = do[(slice(None),) + (None,) * zero.ndim]
+        state["stats"] = jnp.where(rows, zero[None], state["stats"])
     state["since_attempt"] = jnp.where(do, 0.0, state["since_attempt"])
     state["n_nodes"] = base + n_new
     state["n_splits"] = state["n_splits"] + jnp.sum(do.astype(i32))

@@ -113,6 +113,65 @@ def test_vht_stats_partial_node_block_is_exact():
         np.asarray(out), np.asarray(stats_update_ref(stats, leaf, xbin, y, w)))
 
 
+@pytest.mark.parametrize("moments,R,m,nb,C", [
+    ("vht", 40, 24, 8, 2),        # class one-hot x weight
+    ("amrules", 65, 32, 8, 3),    # (w, w*y, w*y^2), float moments
+])
+def test_rule_stats_packed_entry_equals_4d(moments, R, m, nb, C):
+    """The packed entry ([R, m*bins*C] in and out, no reshape) computes
+    exactly what the 4-D entry does, reshaped."""
+    from repro.kernels.rule_stats.kernel import packs, rule_stats_pallas
+    from repro.kernels.rule_stats.ops import rule_moments
+    ks = jax.random.split(jax.random.PRNGKey(R + m), 5)
+    B = 64
+    stats = jnp.floor(jax.random.uniform(ks[0], (R, m, nb, C)) * 5)
+    seg = jax.random.randint(ks[1], (B,), 0, R + 1)   # R = discarded
+    xbin = jax.random.randint(ks[2], (B, m), 0, nb)
+    w = jnp.where(jnp.arange(B) % 3 == 0, 0.0, 1.0)
+    if moments == "vht":
+        mom = jax.nn.one_hot(jax.random.randint(ks[3], (B,), 0, C), C) \
+            * w[:, None]
+    else:
+        mom = rule_moments(jax.random.normal(ks[4], (B,)), w)
+    assert packs(m, nb * C)
+    want = rule_stats_pallas(stats, seg, xbin, mom, interpret=True)
+    got = rule_stats_pallas(stats.reshape(R, -1), seg, xbin, mom,
+                            interpret=True)
+    assert got.shape == (R, m * nb * C)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want).reshape(R, -1))
+
+
+def test_vht_stats_packed_entry_equals_4d():
+    N, m, nb, C, B = 40, 24, 8, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    stats = jnp.floor(jax.random.uniform(ks[0], (N, m, nb, C)) * 5)
+    leaf = jax.random.randint(ks[1], (B,), 0, N)
+    xbin = jax.random.randint(ks[2], (B, m), 0, nb)
+    y = jax.random.randint(ks[3], (B,), 0, C)
+    w = jnp.where(jnp.arange(B) % 3 == 0, 0.0, 1.0)
+    want = stats_update(stats, leaf, xbin, y, w, impl="pallas",
+                        interpret=True)
+    got = stats_update(stats.reshape(N, -1), leaf, xbin, y, w,
+                       impl="pallas", interpret=True, n_classes=C)
+    assert got.shape == (N, m * nb * C)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(want).reshape(N, -1))
+
+
+def test_packed_entry_refuses_a_padded_width():
+    """A width the kernel pads is not offered packed: padding every call
+    would bring the relayout back."""
+    from repro.kernels.rule_stats.kernel import packs, rule_stats_pallas
+    N, m, nb, C, B = 8, 68, 8, 2, 64
+    assert not packs(m, nb * C)
+    with pytest.raises(ValueError, match="padding"):
+        rule_stats_pallas(jnp.zeros((N, m * nb * C)),
+                          jnp.zeros((B,), jnp.int32),
+                          jnp.zeros((B, m), jnp.int32), jnp.zeros((B, C)),
+                          interpret=True)
+
+
 # --------------------------- flash_attention --------------------------------
 
 @pytest.mark.parametrize("B,S,H,K,hd,dtype", [
