@@ -16,6 +16,15 @@ is found from those names, so a new cell is a new file and a new entry:
   the configuration needs, for roofline shares;
 * ``bench/peaks.json``: the peaks of each device kind.
 
+A configuration may give ``mesh``, named axes and their sizes in the
+program's convention (``{"model": 4}``: attributes split over four
+chips); its product is the workload's ``chips``.  Without it the
+configuration runs on one chip.  One chip runs the program's
+``JitEngine`` on the default device; more run its ``ShardMapEngine`` over
+a mesh of the first ``chips`` devices, the feed draws every chunk
+replicated on that mesh, and the family's reference is given the mesh
+(``ref_init(cfg, mesh=mesh)``) to place its state over the same chips.
+
 A run: set-up (compile cache, warm-up of the cell's own shapes), a window
 of whole chunks of prequential training -- ``seconds`` times the
 configuration's ``chunks_per_s``, a fixed amount of work -- with the
@@ -31,6 +40,7 @@ import gc
 import importlib
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
@@ -89,6 +99,18 @@ class Cell:
         if predict_overrides:
             self.traffic["predict"] = dict(self.traffic["predict"],
                                            **predict_overrides)
+        self.mesh_shape = dict(self.cfg.get("mesh") or {})
+        laid_over = math.prod(self.mesh_shape.values())
+        if laid_over != self.chips:
+            raise ValueError(
+                f"workload {workload!r} asks for {self.chips} chips, but "
+                f"configuration {self.config_name!r} is laid over "
+                f"{laid_over} (mesh {self.mesh_shape or 'none'})")
+        if self.chips > 1 and (self.traffic.get("publish")
+                               or self.traffic.get("predict")):
+            raise NotImplementedError(
+                f"workload {workload!r}: publishing or serving on "
+                f"{self.chips} chips is not supported")
         self.family = importlib.import_module(
             f"bench.families.{self.cfg['family']}")
         self.end_to_end = [m for m in bm["end_to_end"]
@@ -154,6 +176,31 @@ def require_chips(n: int):
         raise NoDevice(f"needs a TPU; JAX found {devs[0].platform}")
     if len(devs) < n:
         raise NoDevice(f"needs {n} chips; JAX found {len(devs)}")
+
+
+def make_mesh(cell: Cell):
+    """The cell's mesh over its first ``chips`` devices, with the
+    configuration's axes; None on one chip."""
+    if cell.chips == 1:
+        return None
+    from repro.launch.mesh import auto_mesh
+    return auto_mesh(tuple(cell.mesh_shape.values()),
+                     tuple(cell.mesh_shape))
+
+
+def make_engine(mesh):
+    """The program's engine for a cell: ``JitEngine`` on one chip,
+    ``ShardMapEngine`` over the cell's mesh."""
+    from repro.core.engines import JitEngine, ShardMapEngine
+    return JitEngine() if mesh is None else ShardMapEngine(mesh)
+
+
+def memory_peak(devices):
+    """(the fullest device's peak bytes in use, each device's), None
+    where a device does not say."""
+    each = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+    return max((b for b in each if b is not None), default=None), each
 
 
 def feed(cell: Cell):
@@ -259,34 +306,61 @@ def breakdown(dev: dict, n: int = 10) -> dict:
                           zip(dev["gap_labels"], dev["gaps"])][:n]}
 
 
+def host_shards(x):
+    """``(index, host copy)`` of each distinct part of ``x``: a device
+    array's addressable shards, one of each group of replicas; a host
+    array is one part, itself."""
+    import jax
+    if isinstance(x, jax.Array):
+        for s in x.addressable_shards:
+            if s.replica_id == 0:
+                yield s.index, np.asarray(s.data)
+    else:
+        x = np.asarray(x)
+        yield (slice(None),) * x.ndim, x
+
+
 def compare(fam, prog: dict, ref: dict) -> tuple[int, float]:
     """(elements that differ among the exactly compared state arrays,
-    largest relative gap among the others)."""
+    largest relative gap among the others).  ``prog`` is the program's
+    state on the host; ``ref`` the reference's, compared part by part
+    (``host_shards``), so the host holds one shard of it at a time."""
     mismatch = 0
     for k in fam.EXACT:
-        a, b = np.asarray(prog[k]), np.asarray(ref[k])
-        mismatch += a.size if a.shape != b.shape else \
-            int(np.count_nonzero(a != b))
-    scales = [float(np.max(np.abs(ref[k]))) for k in fam.FLOAT]
+        a = np.asarray(prog[k])
+        if a.shape != ref[k].shape:
+            mismatch += a.size
+            continue
+        for idx, b in host_shards(ref[k]):
+            mismatch += int(np.count_nonzero(a[idx] != b))
+    scales = [max(float(np.max(np.abs(b))) for _, b in host_shards(ref[k]))
+              for k in fam.FLOAT]
     floor = float(np.median(scales)) if scales else 1.0
     gap = 0.0
     for k, scale in zip(fam.FLOAT, scales):
-        a = np.asarray(prog[k], np.float64)
-        b = np.asarray(ref[k], np.float64)
-        if a.shape != b.shape or not np.all(np.isfinite(a)):
-            return mismatch, 1e30      # not finite: no gap to measure
-        d = float(np.max(np.abs(a - b))) if a.size else 0.0
+        a = np.asarray(prog[k])
+        if a.shape != ref[k].shape:
+            return mismatch, 1e30
+        d = 0.0
+        for idx, b in host_shards(ref[k]):
+            part = np.asarray(a[idx], np.float64)
+            if not np.all(np.isfinite(part)):
+                return mismatch, 1e30  # not finite: no gap to measure
+            if part.size:
+                d = max(d, float(np.max(np.abs(
+                    part - np.asarray(b, np.float64)))))
         gap = max(gap, d / max(scale, floor, 1e-30))
     return mismatch, gap
 
 
-def replay(fam, cfg: dict, chunk, n_chunks: int, served, rows):
+def replay(fam, cfg: dict, chunk, n_chunks: int, served, rows, mesh=None):
     """The reference over chunks 0..n_chunks-1: (its final state in the
-    program's layout, its prequential metric summed chunk by chunk in the
-    order the program's is, the served answers that differ from its
-    prediction at the chunk whose snapshot answered them)."""
+    program's layout, left on the device, its prequential metric summed
+    chunk by chunk in the order the program's is, the served answers that
+    differ from its prediction at the chunk whose snapshot answered
+    them).  On a mesh the reference places its state over it."""
     import jax.numpy as jnp
-    s = fam.ref_init(cfg)
+    s = fam.ref_init(cfg) if mesh is None else fam.ref_init(cfg, mesh=mesh)
     per_chunk = []
     by_chunk = defaultdict(list)
     for c, i, p in served:
@@ -305,8 +379,7 @@ def replay(fam, cfg: dict, chunk, n_chunks: int, served, rows):
             got = np.asarray([p for _, p in by_chunk[c]])
             wrong += int(np.count_nonzero(got != want))
     total = sum(np.asarray(p, np.float64).sum() for p in per_chunk)
-    return {k: np.asarray(v) for k, v in fam.ref_view(s).items()}, \
-        total, wrong
+    return fam.ref_view(s), total, wrong
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -321,7 +394,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if check_device:
         require_chips(cell.chips)
     sys.path.insert(0, str(ROOT / "src"))
-    from repro.core.engines import JitEngine
     from repro.core.evaluation import ChunkedPrequentialEvaluation
     from repro.data.pipeline import ChunkedStream
     from repro.serving.snapshot import model_state_of
@@ -332,7 +404,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     L = cfg["chunk_len"]
     key = seed_key(seed)
     counter = CompileCounter()
-    draw = feed(cell).chunk_fn(cell, key)
+    mesh = make_mesh(cell)
+    draw = feed(cell).chunk_fn(cell, key, mesh)
     drawn_at = []                   # host clock at each chunk's draw
 
     def chunk(i):
@@ -341,7 +414,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             return draw(i)
 
     learner = fam.learner(cfg)
-    engine = JitEngine()
+    engine = make_engine(mesh)
     predict = tr.get("predict")
     publisher = server = load = None
     if tr.get("publish") or predict:
@@ -423,7 +496,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     dev0 = jax.devices()[0]
-    mem = (dev0.memory_stats() or {}).get("peak_bytes_in_use")
+    mem, chip_peaks = memory_peak(jax.devices()[:cell.chips])
     seen = float(res.extra["seen"])
     prog_metric = float(res.metric)
     prog = {k: np.asarray(v) for k, v in
@@ -447,10 +520,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     t_ref = time.perf_counter()
     with TraceAnnotation("bench.reference"):
         ref, ref_total, served_wrong = replay(
-            fam, cfg, chunk, n_chunks, served, rows if predict else None)
+            fam, cfg, chunk, n_chunks, served, rows if predict else None,
+            mesh)
+        jax.block_until_ready(ref)
     ref_s = time.perf_counter() - t_ref
 
+    t_cmp = time.perf_counter()
     mismatch, float_gap = compare(fam, prog, ref)
+    compare_s = time.perf_counter() - t_cmp
+    del ref
     mname, mgap = fam.metric_gap(prog_metric, ref_total, seen)
     numbers = {"state_mismatch": mismatch, mname: mgap}
     if fam.FLOAT:
@@ -471,7 +549,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         cfg=cfg, setup_s=setup_s, window_s=window_s, instances=seen,
         steps=n_chunks * L, window_compiles=window_compiles, trace=tsum,
         server=status, server0=server0, latencies_ms=lat, late_ms=late,
-        work=cell.work(),
+        work=cell.work(), chips=cell.chips, mesh=cell.mesh_shape or None,
         peaks=peaks(dev0.device_kind) if check_device else None)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
@@ -489,10 +567,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         if devs:
             out["breakdown"] = breakdown(devs[0])
     log(f"window: {n_chunks} chunks, {seen:.0f} instances, "
-        f"{window_s:.3f} s; reference {ref_s:.3f} s; setup {setup_s:.3f} s")
+        f"{window_s:.3f} s; reference {ref_s:.3f} s; compare "
+        f"{compare_s:.3f} s; setup {setup_s:.3f} s")
     out["report"] = {
         "window_chunks": n_chunks, "window_s": window_s,
-        "reference_s": ref_s, "server": status,
+        "reference_s": ref_s, "compare_s": compare_s, "server": status,
         "longest_draw_gap_s": float(draw_gaps.max()) if draw_gaps.size
         else None,
         "longest_draw_gap_at": int(draw_gaps.argmax()) + 1
@@ -500,6 +579,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         "gc_s": gc_clock.seconds, "longest_gc_s": gc_clock.longest,
         "longest_host_pause_s": pauses.longest,
         "longest_host_pause_at_s": pauses.at}
+    if cell.chips > 1:
+        out["report"]["memory_peak_bytes_per_chip"] = chip_peaks
     if n_req:
         half = len(lat) // 2
         p95 = lambda v: float(np.percentile(v, 95, method="higher"))

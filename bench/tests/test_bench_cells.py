@@ -36,6 +36,27 @@ def test_cell_runs_correct(workload, monkeypatch):
         assert "served_wrong" in out["checks"]
 
 
+def test_one_chip_cell_runs_a_jit_engine_and_enters_no_mesh(monkeypatch):
+    from bench import harness
+    from repro.core import engines
+    made = []
+    make_engine = harness.make_engine
+
+    def spy(mesh):
+        made.append((mesh, make_engine(mesh)))
+        return made[-1][1]
+
+    def no_mesh(mesh):
+        raise AssertionError("a one-chip cell entered a mesh")
+
+    monkeypatch.setattr(harness, "make_engine", spy)
+    monkeypatch.setattr(engines, "mesh_context", no_mesh)
+    out = tiny.run("vht-dense1000.train", monkeypatch, chunks=3)
+    assert out["correct"], out["checks"]
+    assert [(m, type(e)) for m, e in made] == [(None, engines.JitEngine)]
+    assert "memory_peak_bytes_per_chip" not in out["report"]
+
+
 @pytest.mark.parametrize("workload", CELLS)
 def test_traced_run_reports_per_layer_metrics(workload, monkeypatch):
     out = tiny.run(workload, monkeypatch, trace=True, chunks=4)

@@ -3,6 +3,7 @@ traffic mix and metric is found by name; the runner refuses to run
 without a chip."""
 
 import json
+import math
 import os
 import re
 import shutil
@@ -60,6 +61,37 @@ def test_every_name_finds_its_file():
     for m in BM["end_to_end"]:
         assert callable(harness.load_module(
             ROOT / "bench" / "metrics" / f"{m['name']}.py").read)
+
+
+def test_chips_are_what_the_configuration_is_laid_over():
+    for w in BM["workloads"]:
+        mesh = harness.Cell(w["name"]).cfg.get("mesh") or {}
+        assert w["chips"] == math.prod(mesh.values())
+
+
+def _scratch(chips, traffic="train"):
+    bm = dict(BM, workloads=[{"name": "scratch", "config": "vht-dense1000",
+                              "traffic": traffic, "chips": chips,
+                              "why": "test"}])
+    return lambda **kw: harness.Cell("scratch", benchmark=bm, **kw)
+
+
+@pytest.mark.parametrize("chips, mesh", [(4, None), (4, {"model": 2}),
+                                         (1, {"model": 4}),
+                                         (4, {"model": 2, "data": 1})])
+def test_cell_refuses_chips_its_mesh_does_not_make(chips, mesh):
+    with pytest.raises(ValueError, match="laid over"):
+        _scratch(chips)(overrides={"mesh": mesh})
+
+
+def test_cell_takes_chips_its_mesh_makes():
+    cell = _scratch(4)(overrides={"mesh": {"model": 2, "data": 2}})
+    assert cell.chips == 4 and cell.mesh_shape == {"model": 2, "data": 2}
+
+
+def test_serving_on_a_mesh_is_not_supported():
+    with pytest.raises(NotImplementedError, match="not supported"):
+        _scratch(4, "serve")(overrides={"mesh": {"model": 4}})
 
 
 def test_unknown_device_kind_is_an_error():

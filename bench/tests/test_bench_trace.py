@@ -57,6 +57,28 @@ def test_busy_idle_self_time_and_gaps_of_a_synthetic_trace():
     assert bd["device_ops"][0] == ["copy.3", pytest.approx(100e-9)]
 
 
+def test_collective_time_is_the_self_time_of_collectives():
+    device = _plane("/device:TPU:0", [
+        ("XLA Ops", [
+            ("%while.1 = (f32[8]) while((f32[8]) %p), body=%b", 0, 300),
+            ("%all-gather-start.1 = (f32[2]{0}, f32[8]{0}) "
+             "all-gather-start(f32[2]{0} %p), dimensions={0}", 10, 20),
+            ("%all-gather-done.1 = f32[8]{0} "
+             "all-gather-done((f32[2]{0}, f32[8]{0}) %all-gather-start.1)",
+             40, 30),
+            ("%all-reduce.3 = f32[] all-reduce(f32[] %x), to_apply=%add",
+             100, 50),
+            ("%fusion.2 = f32[8]{0:T(128)S(1)} fusion(f32[8] %all-reduce.3),"
+             " kind=kLoop, calls=%fused_computation", 200, 40),
+            ("%copy.3 = f32[8] copy(f32[8] %all-gather-done.1), "
+             "metadata={op_name=\"all-gather(\"}", 250, 10)]),
+        ("XLA Modules", [("jit_chunk_fn(1)", 0, 300)])])
+    host = _plane("/host:CPU", [("python3", [("bench.window", 0, 400)])])
+    dev = trace.summarize([device, host])["devices"][0]
+    assert dev["collective_s"] == pytest.approx(100e-9)
+    assert dev["busy_s"] == pytest.approx(300e-9)
+
+
 def test_only_the_window_is_counted():
     """Device work before and after the ``bench.window`` span (set-up, the
     answers to a serving cell's last requests) is cut off."""
@@ -115,4 +137,6 @@ def test_recorded_chip_trace_reduces_to_what_the_run_reported():
     bd = harness.breakdown(dev)
     assert bd == json.loads(json.dumps(recorded["breakdown"]))
     assert any(op.startswith("_stats_update") for op, _ in bd["device_ops"])
+    # one chip: no collective among its operations
+    assert dev["collective_s"] == 0
     assert "bench.window" in s["spans"]

@@ -9,7 +9,9 @@ ran (busy time), the time of each operation by name, the time of each
 compiled program (XLA module) by name, and the idle gaps between
 operations; on the host, the spans the benchmark wrapped around its own
 calls (``bench.*`` ``TraceAnnotation``s) and the host events that overlap
-each idle gap, by which the gaps are labelled.  Work after the window
+each idle gap, by which the gaps are labelled.  Each device's
+``collective_s`` is the self time of its collective operations
+(``COLLECTIVES``, with their ``-start``/``-done`` forms).  Work after the window
 (the answers of a serving cell's last requests, the reference) is not
 counted.
 """
@@ -18,6 +20,7 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from collections import defaultdict
 
 DEVICE_PREFIX = "/device:TPU:"
@@ -26,6 +29,11 @@ MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+# an operation's event is its HLO text, ``%name = shape opcode(...)``:
+# the opcode is the first word after the shape that opens a parenthesis
+_OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -74,6 +82,19 @@ def _self_time(ops) -> dict:
     for e, n, dd, nested in stack:
         out[n] += (dd - nested) * 1e-9
     return dict(out)
+
+
+def is_collective(op: str) -> bool:
+    """Whether an operation's event is a collective's: by its opcode, or,
+    where the event is a bare name, by that name less its number."""
+    if " = " in op:
+        m = _OPCODE.search(" " + op.split(" = ", 1)[1])
+        kind = m.group(1) if m else ""
+    else:
+        kind = op.lstrip("%").split(".")[0]
+    for form in ("-start", "-done"):
+        kind = kind.removesuffix(form)
+    return kind in COLLECTIVES
 
 
 def _strip(name: str) -> str:
@@ -130,6 +151,8 @@ def summarize(planes, *, n_gaps: int = 10) -> dict:
             "name": name,
             "busy_s": sum(e - s for s, e in busy) * 1e-9,
             "op_self_time": op_time,
+            "collective_s": sum(t for n, t in op_time.items()
+                                if is_collective(n)),
             "module_time": dict(module_time),
             "gaps": [(g[1] - g[0]) * 1e-9 for g in gaps],
             "gap_labels": [_label(g, host) for g in gaps],
